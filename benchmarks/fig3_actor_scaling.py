@@ -61,6 +61,7 @@ from repro.core.provisioning import fit_paper_actor_model
 from repro.core.system import SeedSystem
 from repro.envs.alesim import ALESimEnv
 from repro.envs.catch import CatchEnv
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def measured_sweep(actor_counts=(1, 2, 4, 8), seconds=1.2, step_cost=2048,
@@ -967,6 +968,7 @@ def run_autoscale(args, sec, out_dir="."):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny measured windows (CI: exercise the path)")

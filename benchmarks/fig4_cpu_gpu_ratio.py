@@ -31,6 +31,7 @@ from repro.core.provisioning import (SystemModel, cpu_gpu_ratio,
 from repro.core.system import SeedSystem
 from repro.envs.catch import CatchEnv
 from repro.hw import DGX1_HOST, TPU_V5E, V100, V5E_HOST
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _policy_step(obs, ids):
@@ -184,6 +185,7 @@ def transport_model_check(rows, num_actors, envs_per_actor, t_rtt,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny measured windows (CI: exercise the wire path)")

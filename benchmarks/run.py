@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from benchmarks import fig2_breakdown, fig3_actor_scaling, fig4_cpu_gpu_ratio
 from benchmarks import roofline as roofline_bench
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def microbench_train_step():
@@ -54,6 +55,7 @@ def microbench_train_step():
 
 
 def main() -> None:
+    enable_compile_cache()
     print("=" * 72)
     print("== Fig 2: GPU/TPU bottleneck breakdown (sequential idealization)")
     fig2_breakdown.main()

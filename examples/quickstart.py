@@ -27,6 +27,7 @@ from repro.core.losses import init_train_state, make_train_step
 from repro.core.system import SeedSystem
 from repro.envs.catch import CatchEnv
 from repro.envs.tokenworld import synthetic_vtrace_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import greedy_generate
 from repro.optim import adamw
 
@@ -320,6 +321,7 @@ def chaos_demo(E=4, seconds=1.5):
 
 
 def main():
+    enable_compile_cache()
     arch = sys.argv[1] if len(sys.argv) > 1 else "qwen3-14b"
     cfg = smoke_config(arch)
     bundle = make_model(cfg)

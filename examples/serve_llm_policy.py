@@ -20,10 +20,12 @@ import numpy as np
 
 from repro.configs.registry import make_model, smoke_config
 from repro.core.inference import InferenceServer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import make_prefill, make_serve_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-9b")
     ap.add_argument("--clients", type=int, default=6)

@@ -1,5 +1,6 @@
-"""The paper's exact workload, end to end on CPU: SEED-style distributed
-R2D2 on an ALE stand-in.
+"""The paper's workload at reduced widths, end to end: SEED-style
+distributed R2D2 on an ALE stand-in (`chip_smoke.py` runs the same wiring,
+`repro.core.r2d2_agent`, at the published widths on a TPU).
 
 Actor threads step the env and query the central inference server (which
 owns per-actor LSTM state, SEED-style); unrolls land in prioritized
@@ -11,21 +12,13 @@ fresh params. Reports the Fig-3 quantities (frames/s, batch occupancy).
 
 import argparse
 import sys
-import threading
 
 sys.path.insert(0, "src")
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
 from repro.configs.r2d2_atari import AtariConfig
-from repro.core.losses import init_train_state, make_train_step
-from repro.core.system import SeedSystem
+from repro.core.r2d2_agent import build_r2d2_system
 from repro.envs.alesim import ALESimEnv
-from repro.models.atari import make_atari
-from repro.nn.recurrent import lstm_state_init
-from repro.optim import adamw
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -36,89 +29,27 @@ def main():
     ap.add_argument("--seconds", type=float, default=8.0)
     ap.add_argument("--frame", type=int, default=42)
     args = ap.parse_args()
+    enable_compile_cache()
 
+    # reduced widths so the example trains on a CPU in seconds; chip_smoke.py
+    # runs the same wiring at the published AtariConfig() widths
     acfg = AtariConfig(obs_size=args.frame, obs_channels=2, core_dim=128,
                        num_actions=6, burn_in=4, unroll=16, n_step=3,
                        target_update_period=50)
-    bundle = make_atari(acfg)
-    opt = adamw(5e-4)
-    rng = jax.random.PRNGKey(0)
-    state = init_train_state(bundle, opt, rng, with_target=True)
-    # no donation here: the inference thread reads live["params"] while the
-    # learner steps, so the old buffers must stay alive (a real deployment
-    # double-buffers published params; this example keeps it simple).
-    train_step = jax.jit(make_train_step(bundle, opt, algo="r2d2", acfg=acfg))
-
-    # central inference: owns per-LANE LSTM state (SEED's key design); the
-    # server hands policy_step dense (actor, env) slot ids, so state is
-    # sized for all actors x lanes.
-    params_lock = threading.Lock()
-    live = {"params": state["params"]}
-    n_slots = max(64, args.actors * args.envs_per_actor)
-    core = {"h": np.zeros((n_slots, acfg.core_dim), np.float32),
-            "c": np.zeros((n_slots, acfg.core_dim), np.float32)}
-    eps = 0.2
-
-    @jax.jit
-    def _policy(params, obs, h, c):
-        q, (h2, c2) = bundle.decode_step(params, obs, (h, c))
-        return jnp.argmax(q, -1), h2, c2
-
-    def policy_step(obs, ids):
-        with params_lock:
-            p = live["params"]
-        h = jnp.asarray(core["h"][ids])
-        c = jnp.asarray(core["c"][ids])
-        a, h2, c2 = _policy(p, jnp.asarray(obs), h, c)
-        core["h"][ids] = np.asarray(h2)
-        core["c"][ids] = np.asarray(c2)
-        a = np.asarray(a)
-        explore = np.random.random(a.shape) < eps
-        return np.where(explore, np.random.randint(0, acfg.num_actions, a.shape), a)
-
-    seq_len = acfg.burn_in + acfg.unroll
-
-    def wrapped_train_step(st, batch):
-        b = batch["obs"].shape[0]
-        jb = {
-            "obs": jnp.asarray(batch["obs"]),
-            "actions": jnp.asarray(batch["actions"], jnp.int32),
-            "rewards": jnp.asarray(batch["rewards"]),
-            "dones": jnp.asarray(batch["dones"]),
-            "core": lstm_state_init(b, acfg.core_dim),
-        }
-        st, metrics = train_step(st, jb)
-        with params_lock:
-            live["params"] = st["params"]
-        return st, metrics
-
-    # precompile both jitted paths so the measured window is steady-state
-    lanes = args.actors * args.envs_per_actor
-    dummy_obs = np.zeros((lanes, args.frame, args.frame, 2), np.uint8)
-    policy_step(dummy_obs, np.arange(lanes))
-    dummy = {
-        "obs": np.zeros((2, seq_len, args.frame, args.frame, 2), np.uint8),
-        "actions": np.zeros((2, seq_len), np.int32),
-        "rewards": np.zeros((2, seq_len), np.float32),
-        "dones": np.zeros((2, seq_len), np.float32),
-    }
-    state, _ = wrapped_train_step(state, dummy)
-
-    sys_ = SeedSystem(
-        env_factory=lambda: ALESimEnv(frame=args.frame, channels=2,
-                                      step_cost=512, episode_len=200),
-        policy_step=policy_step, num_actors=args.actors, unroll=seq_len,
-        envs_per_actor=args.envs_per_actor,
-        train_step=wrapped_train_step, state=state, learner_batch=2,
-        replay_capacity=256, min_replay=2, deadline_ms=4.0)
+    sys_ = build_r2d2_system(
+        acfg, lambda: ALESimEnv(frame=args.frame, channels=2, step_cost=512,
+                                episode_len=200),
+        num_actors=args.actors, envs_per_actor=args.envs_per_actor,
+        learner_batch=2, replay_capacity=256, min_replay=2)
 
     print(f"== SEED R2D2: {args.actors} actors x {args.envs_per_actor} env "
           f"lanes, {args.seconds}s wall-clock")
     stats = sys_.run(seconds=args.seconds)
     for k, v in stats.items():
         print(f"  {k:24s} {v:.3f}" if isinstance(v, float) else f"  {k:24s} {v}")
-    if stats["learner_error"]:
-        raise SystemExit(f"learner died:\n{stats['learner_error']}")
+    for key in ("learner_error", "inference_error"):
+        if stats[key]:
+            raise SystemExit(f"{key}:\n{stats[key]}")
     assert stats["env_frames"] > 0 and stats["learner_steps"] > 0
     print("ok — actors, central inference, replay and learner all ran")
 

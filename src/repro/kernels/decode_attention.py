@@ -18,6 +18,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, scale, block_s, block_h, n_s):
+    bi = pl.program_id(0)
     si = pl.program_id(2)
 
     @pl.when(si == 0)
@@ -29,17 +30,20 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     q = q_ref[0].astype(jnp.float32)               # (bh, d)
     k = k_ref[0].astype(jnp.float32)               # (bs, bh, d)
     v = v_ref[0].astype(jnp.float32)
-    s = jnp.einsum("hd,shd->hs", q, k) * scale     # (bh, bs)
-    valid = (si * block_s + jax.lax.broadcasted_iota(jnp.int32, (block_h, block_s), 1)
-             ) < len_ref[0]
+    # one query row per head: multiply-reduce on the VPU. The head axis sits
+    # between S and D in the cache layout, and Mosaic only lowers batched
+    # dots whose batch axis leads both operands
+    s = jnp.sum(q[None] * k, axis=-1) * scale      # (bs, bh)
+    valid = (si * block_s + jax.lax.broadcasted_iota(jnp.int32, (block_s, block_h), 0)
+             ) < len_ref[bi]
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, s.max(axis=0))
+    p = jnp.exp(s - m_new[None, :])
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.einsum("hs,shd->hd", p, v)
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=0)
+    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.sum(p[:, :, None] * v, axis=0)
     m_ref[...] = m_new
 
     @pl.when(si == n_s - 1)
@@ -65,8 +69,9 @@ def decode_attention(q, k, v, lengths, *, scale=None, block_s=512,
         kernel,
         grid=(b, n_h, n_s),
         in_specs=[
-            pl.BlockSpec((1,), lambda b_, hi, si: (b_,),
-                         memory_space=pltpu.SMEM),
+            # the whole (B,) lengths vector sits in SMEM; a (1,) block per
+            # batch row is not tile-aligned and Mosaic refuses it
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_h, d), lambda b_, hi, si: (b_, hi, 0)),
             pl.BlockSpec((1, block_s, block_h, d),
                          lambda b_, hi, si: (b_, si, hi, 0)),

@@ -24,12 +24,14 @@ def _rglru_kernel(a_ref, b_ref, y_ref, h_ref, *, block_s):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0].astype(jnp.float32)     # (block_s, W)
-    b = b_ref[0].astype(jnp.float32)
-
     def body(t, h):
-        h = a[t] * h + b[t]
-        y_ref[0, t, :] = h.astype(y_ref.dtype)
+        # one (1, W) row per step, read and written through the refs:
+        # Mosaic lowers a dynamic ref slice but not a dynamic row index
+        # into a loaded value
+        row = pl.ds(t, 1)
+        h = (a_ref[0, row, :].astype(jnp.float32) * h
+             + b_ref[0, row, :].astype(jnp.float32))
+        y_ref[0, row, :] = h.astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, block_s, body, h_ref[...])
@@ -53,6 +55,6 @@ def rglru_scan(a, b, *, block_s=256, block_w=None, interpret=False):
         out_specs=pl.BlockSpec((1, block_s, block_w),
                                lambda b_, wi, si: (b_, si, wi)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), a.dtype),
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
     )(a, b)

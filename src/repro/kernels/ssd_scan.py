@@ -19,37 +19,46 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref, *,
                 chunk):
+    bi = pl.program_id(0)
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    a = a_ref[0]                                     # scalar A (negative)
+    a = a_ref[bi]                                    # scalar A (negative)
     x = x_ref[0, 0].astype(jnp.float32)              # (L, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)            # (L,)
+    dt = dt_ref[0, 0].astype(jnp.float32)            # (1, L) row
     b = b_ref[0, 0].astype(jnp.float32)              # (L, N)
     c = c_ref[0, 0].astype(jnp.float32)              # (L, N)
 
-    da = dt * a                                      # (L,)
-    cs = jnp.cumsum(da)                              # (L,)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = rows >= cols                            # [t, s]: s <= t
+    # inclusive cumsum of dt*a as a matmul against the causal mask, at
+    # full f32 precision: the sums are exponentiated below
+    da = dt * a                                      # (1, L)
+    cs_row = jax.lax.dot_general(da, causal.astype(jnp.float32),
+                                 (((1,), (1,)), ((), ())),
+                                 precision=jax.lax.Precision.HIGHEST)  # (1, L)
+    cs_col = cs_row.reshape(chunk, 1)                # (L, 1)
+    total = jnp.sum(da)                              # cs at the last step
     # intra-chunk: M[t,s] = (C_t.B_s) * exp(cs_t - cs_s) * dt_s,  s <= t
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())))   # (L, L)
-    decay = cs[:, None] - cs[None, :]
-    causal = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    m = scores * jnp.exp(jnp.where(causal, decay, -jnp.inf)) * dt[None, :]
+    decay = cs_col - cs_row
+    m = scores * jnp.exp(jnp.where(causal, decay, -jnp.inf)) * dt
     y = jax.lax.dot(m, x)                            # (L, P)
 
     # inter-chunk: y += exp(cs_t) * C_t . S_prev
     state = state_ref[...]                           # (P, N)
-    y += jnp.exp(cs)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cs_col) * jax.lax.dot_general(
         c, state, (((1,), (1,)), ((), ())))          # (L, P)
 
     # state update: S = exp(sum da) * S + X^T (w ⊙ B), w_s = exp(cs_L - cs_s) dt_s
-    w = jnp.exp(cs[-1] - cs) * dt                    # (L,)
-    upd = jax.lax.dot_general(x, w[:, None] * b, (((0,), (0,)), ((), ())))
-    state_ref[...] = jnp.exp(cs[-1]) * state + upd
+    w = jnp.exp(total - cs_row) * dt                 # (1, L)
+    upd = jax.lax.dot_general(x, w.reshape(chunk, 1) * b,
+                              (((0,), (0,)), ((), ())))
+    state_ref[...] = jnp.exp(total) * state + upd
     y_ref[0, 0, ...] = y.astype(y_ref.dtype)
 
 
@@ -61,7 +70,7 @@ def ssd_scan(x, dt, a, b, c, *, chunk=256, interpret=False):
     assert s % chunk == 0
     nc = s // chunk
     xc = x.reshape(bh, nc, chunk, p)
-    dtc = dt.reshape(bh, nc, chunk)
+    dtc = dt.reshape(bh, nc, 1, chunk)
     bc = b.reshape(bh, nc, chunk, n)
     cc = c.reshape(bh, nc, chunk, n)
 
@@ -70,9 +79,10 @@ def ssd_scan(x, dt, a, b, c, *, chunk=256, interpret=False):
         kernel,
         grid=(bh, nc),
         in_specs=[
-            pl.BlockSpec((1,), lambda b_, ci: (b_,), memory_space=pltpu.SMEM),
+            # whole (BH,) vector in SMEM: a (1,) block is not tile-aligned
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, chunk, p), lambda b_, ci: (b_, ci, 0, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b_, ci: (b_, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b_, ci: (b_, ci, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda b_, ci: (b_, ci, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda b_, ci: (b_, ci, 0, 0)),
         ],

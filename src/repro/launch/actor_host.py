@@ -20,7 +20,10 @@ sink.
 
 Processes are spawned (never forked: JAX holds threads at import time and
 fork would deadlock them), so `env_factory` must be picklable — a class
-like `CatchEnv` or a module-level factory function, not a lambda. Each
+like `CatchEnv` or a module-level factory function, not a lambda. Every
+child runs JAX on the CPU (`_pin_jax_to_cpu`): the parent holds the
+accelerator, which belongs to one process at a time, so a pure-JAX env
+stepping inside a child must never reach for it. Each
 child warms its vector envs up before its measured window, runs for
 `seconds`, then reports counters through a result queue. The parent
 enforces a hard timeout: a wire-level deadlock kills the run with an error
@@ -34,7 +37,9 @@ parity contract `tests/test_transport.py` asserts.
 """
 
 import multiprocessing as mp
+import os
 import queue as _queue
+import sys
 import threading
 import time
 import traceback
@@ -106,11 +111,25 @@ class ActorHostConfig:
     #                              window end
 
 
+def _pin_jax_to_cpu() -> None:
+    """Hold this process's JAX to the CPU whatever the parent's environment
+    says. Runs before anything in the child initialises a JAX backend: the
+    spawn bootstrap may already have imported jax (the parent's main
+    module, the pickled env class), but imports alone pick no platform, so
+    the config update still takes."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+
+
 def run_actor_host(cfg: ActorHostConfig, result_q) -> None:
     """Child entry point: dial the gateway, drive actors, report stats."""
+    _pin_jax_to_cpu()
     stats = {"host_id": cfg.host_id, "elapsed_s": 0.0, "iterations": 0,
              "frames": 0, "episodes": 0, "returns": [], "error": None,
-             "unrolls": 0, "param_lag_total": 0, "epoch": cfg.epoch}
+             "unrolls": 0, "param_lag_total": 0, "epoch": cfg.epoch,
+             "jax_platform": None}
     hb_stop = None
     if cfg.heartbeat:
         # beat from birth: the slow phases (jax import, jit warmup, env
@@ -127,8 +146,6 @@ def run_actor_host(cfg: ActorHostConfig, result_q) -> None:
 
         threading.Thread(target=_beat_loop, daemon=True).start()
     try:
-        import sys
-
         import numpy as np
 
         from repro.core.actor import Actor
@@ -230,6 +247,8 @@ def run_actor_host(cfg: ActorHostConfig, result_q) -> None:
         stats["gateway_failovers"] = sum(
             getattr(tr, "gateway_failovers", 0) for tr in transports)
         stats["returns"] = [r for a in actors for r in a.returns[-20:]]
+        if "jax" in sys.modules:     # where this child's JAX work ran
+            stats["jax_platform"] = sys.modules["jax"].default_backend()
         stats["error"] = next(
             (tr.error for tr in transports if tr.error), None) or next(
             (a.error for a in actors if a.error), None)

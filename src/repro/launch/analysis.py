@@ -73,8 +73,6 @@ def analyze_compiled(lowered, compiled, n_chips: int, chip=TPU_V5E,
     """
     from repro.launch.hlo_cost import module_costs
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per partition
-        cost = cost[0] if cost else {}
     hlo = module_costs(compiled.as_text())
     flops = hlo.flops                                # per-partition
     mem = compiled.memory_analysis()

@@ -31,7 +31,7 @@ from repro.configs.registry import ARCHS, make_model
 from repro.core.losses import make_train_step
 from repro.hw import TPU_V5E
 from repro.launch.analysis import analyze_compiled
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.serve import make_prefill, make_serve_step
 from repro.launch.specs import (batch_specs, cache_specs, params_specs,
                                 rules_for, shardings_of, state_specs)
@@ -63,7 +63,7 @@ def lower_cell(arch: str, shape_name: str, mesh, verbose=False):
     n_chips = mesh.devices.size
     t0 = time.perf_counter()
 
-    with sharding_ctx(mesh, rules), use_mesh(mesh):
+    with sharding_ctx(mesh, rules), jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = adamw(1e-4, moment_dtype=jnp.dtype(cfg.optimizer_dtype))
             step_fn = make_train_step(bundle, opt)

@@ -2,46 +2,24 @@
 
 Defined as FUNCTIONS (not module constants) so importing this module never
 touches jax device state — required because the dry-run must set
-XLA_FLAGS before any jax initialization.
-
-Also the jax-version compat seam: the pinned toolchain (jax 0.4.x) has no
-`jax.sharding.AxisType` (meshes are implicitly Auto) and no `jax.set_mesh`
-(the `Mesh` object itself is the context manager). Everything downstream
-goes through `make_mesh` / `use_mesh` so it runs on both APIs.
+XLA_FLAGS before any jax initialization. Every axis is explicitly Auto.
 """
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n_axes):
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # pinned 0.4.x: every axis is implicitly Auto
-    AxisType = None
-
-    def _axis_kwargs(n_axes):
-        return {}
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; 2x16x16 = 512 chips across 2 pods."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_production_mesh(*, multi_pod: bool = False):
+    """16x16 = 256 chips per pod; 2x16x16 = 512 chips across 2 pods."""
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def single_device_mesh():
-    return jax.make_mesh((1,), ("data",), **_axis_kwargs(1))
-
-
-def use_mesh(mesh):
-    """Context manager activating `mesh` for jitted code under it:
-    `jax.set_mesh` on new jax, the Mesh object itself on 0.4.x."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    return make_mesh((1,), ("data",))

@@ -202,21 +202,11 @@ def moe_ep(cfg, p, x, act="silu"):
                                                  if a not in ep_axes))
         return y.reshape(bl, sl, d), aux
 
-    fn = _shard_map(body, mesh, in_specs=(w_spec, x_spec),
-                    out_specs=(x_spec, P()))
+    # replication checking is off: `body` produces per-shard partial sums
+    # that only the trailing psum replicates
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(w_spec, x_spec),
+                       out_specs=(x_spec, P()), check_vma=False)
     return fn(p, x)
-
-
-def _shard_map(body, mesh, *, in_specs, out_specs):
-    """`jax.shard_map` (>= 0.5, check_vma) or the 0.4.x experimental API
-    (check_rep) — replication checking is off in both: `body` produces
-    per-shard partial sums that only the trailing psum replicates."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map  # jax 0.4.x
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
 
 
 def moe(cfg, p, x, act="silu"):

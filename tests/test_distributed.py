@@ -27,7 +27,7 @@ def test_moe_ep_matches_gather_impl():
     out = run_py("""
         import jax, jax.numpy as jnp
         from repro.configs.base import ModelConfig
-        from repro.launch.mesh import make_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.nn.moe import init_moe, moe, moe_ep
         from repro.sharding.param import ArrayMaker
         from repro.sharding.ctx import sharding_ctx
@@ -41,7 +41,7 @@ def test_moe_ep_matches_gather_impl():
         p = init_moe(ArrayMaker(jax.random.PRNGKey(0)), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
         y_ref, _ = moe(cfg, p, x)
-        with sharding_ctx(mesh, rules), use_mesh(mesh):
+        with sharding_ctx(mesh, rules), jax.set_mesh(mesh):
             y_ep, _ = jax.jit(lambda p, x: moe_ep(cfg, p, x))(p, x)
             g_ref = jax.grad(lambda p, x: moe(cfg.with_(moe_impl='gather'),
                                               p, x)[0].sum())(p, x)
@@ -49,7 +49,7 @@ def test_moe_ep_matches_gather_impl():
         assert err < 1e-5, err
         # full-EP (experts over model+data)
         rules2 = dict(rules, experts=("model", "data"))
-        with sharding_ctx(mesh, rules2), use_mesh(mesh):
+        with sharding_ctx(mesh, rules2), jax.set_mesh(mesh):
             y_full, _ = jax.jit(lambda p, x: moe_ep(cfg, p, x))(p, x)
         err2 = float(jnp.abs(y_ref - y_full).max())
         assert err2 < 1e-5, err2

@@ -1,4 +1,6 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode.
+
+That they compile for the chip is `tests/test_tpu_compile.py`'s job."""
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +40,8 @@ def test_flash_attention(s, d, dtype, window, softcap):
     k = _rand(jax.random.fold_in(K, 1), (b, s, h, d), dtype)
     v = _rand(jax.random.fold_in(K, 2), (b, s, h, d), dtype)
     out = ops.flash_attention(q, k, v, causal=True, window=window,
-                              softcap=softcap, block_q=64, block_k=64)
+                              softcap=softcap, block_q=64, block_k=64,
+                              interpret=True)
     ref = _unfold(R.attention_ref(_fold(q), _fold(k), _fold(v), causal=True,
                                   window=window, softcap=softcap), b, h)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -53,7 +56,7 @@ def test_decode_attention(s, dtype):
     k = _rand(jax.random.fold_in(K, 1), (b, s, h, d), dtype)
     v = _rand(jax.random.fold_in(K, 2), (b, s, h, d), dtype)
     lens = jnp.array([s // 4, s // 2, s], jnp.int32)
-    out = ops.decode_attention(q, k, v, lens, block_s=128)
+    out = ops.decode_attention(q, k, v, lens, block_s=128, interpret=True)
     ref = R.decode_attention_ref(q, k, v, lens)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -68,7 +71,7 @@ def test_ssd_scan(s, p, n, chunk):
     a = -jnp.exp(_rand(jax.random.fold_in(K, 2), (h,), jnp.float32) * 0.3)
     bb = _rand(jax.random.fold_in(K, 3), (b, s, h, n), jnp.float32) * 0.3
     cc = _rand(jax.random.fold_in(K, 4), (b, s, h, n), jnp.float32) * 0.3
-    y = ops.ssd_scan(x, dt, a, bb, cc, chunk=chunk)
+    y = ops.ssd_scan(x, dt, a, bb, cc, chunk=chunk, interpret=True)
     yref, _ = R.ssd_ref(x, dt, a, bb, cc)
     scale = float(jnp.abs(yref).max())
     np.testing.assert_allclose(np.asarray(y), np.asarray(yref),
@@ -80,7 +83,7 @@ def test_rglru_scan(s, w, block_s):
     b = 2
     a = jax.nn.sigmoid(_rand(K, (b, s, w), jnp.float32))
     bb = _rand(jax.random.fold_in(K, 1), (b, s, w), jnp.float32) * 0.1
-    h = ops.rglru_scan(a, bb, block_s=block_s)
+    h = ops.rglru_scan(a, bb, block_s=block_s, interpret=True)
     href, _ = R.rglru_ref(a, bb)
     np.testing.assert_allclose(np.asarray(h), np.asarray(href),
                                atol=1e-5, rtol=1e-5)

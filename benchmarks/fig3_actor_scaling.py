@@ -42,9 +42,9 @@ sidecar thread scrapes `/metrics` + `/healthz` MID-run and the exposition
 must pass the in-repo Prometheus validator (names, TYPE backing, bucket
 monotonicity, +Inf == _count); afterwards a best-of-N in-proc pair gates
 the full ops plane (HTTP server + watchdog + auditor) at < 3% frames/s
-overhead vs telemetry-only. Writes trace.json, metrics.jsonl and
-BENCH_telemetry.json (including the measured ops-overhead delta) to
---out-dir; exits nonzero if any check fails (CI runs
+overhead vs telemetry-only. Writes trace.json and metrics.jsonl to
+--out-dir and prints the results (the measured ops-overhead delta
+included) as one JSON line; exits nonzero if any check fails (CI runs
 `--smoke --telemetry`).
 """
 
@@ -347,9 +347,7 @@ def run_telemetry(args, sec, out_dir="."):
     """
     import threading
 
-    from repro.telemetry import (Telemetry, append_bench_history,
-                                 bench_commit, merge_bench_json,
-                                 validate_prometheus)
+    from repro.telemetry import Telemetry, validate_prometheus
 
     seconds = max(sec * 4, 1.2) if args.smoke else 4.0
     tel = Telemetry(process_name="learner", out_dir=out_dir)
@@ -493,13 +491,7 @@ def run_telemetry(args, sec, out_dir="."):
         "ops_overhead_frac": ops_overhead,
         "failures": failures,
     }
-    merge_bench_json(os.path.join(out_dir, "BENCH_telemetry.json"),
-                     "fig3_telemetry", payload)
-    append_bench_history(
-        os.path.join(out_dir, "BENCH_history.json"), "fig3_telemetry",
-        {"commit": bench_commit(), "ts": time.time(),
-         "frames_per_s": stats["env_frames_per_s"],
-         "smoke": bool(args.smoke)})
+    print(json.dumps({"fig3_telemetry": payload}, sort_keys=True))
 
     print("# fig3g: telemetry validation (socket transport, 2 hosts)")
     print("name,value,derived")
@@ -565,8 +557,8 @@ def run_chaos(args, sec, out_dir="."):
     respawned, the client reconnected, /healthz observed degraded
     mid-run and healthy at the end, and the frame ledger EXACTLY
     conserved. Afterwards the fault-path overhead gate checks the armed-
-    but-idle survival plane costs < 3% frames/s. Writes the results into
-    BENCH_telemetry.json under ``fig3_chaos``; exits nonzero on any
+    but-idle survival plane costs < 3% frames/s. Prints the results as
+    one JSON line under ``fig3_chaos``; exits nonzero on any
     failed check (CI runs ``--smoke --chaos`` under a hard timeout).
     """
     import threading
@@ -576,7 +568,7 @@ def run_chaos(args, sec, out_dir="."):
     from repro.fault import BackoffPolicy, ChaosEvent, ChaosMonkey
     from repro.onpolicy import VTraceLearner, mlp_actor_critic
     from repro.optim import adamw
-    from repro.telemetry import Telemetry, merge_bench_json
+    from repro.telemetry import Telemetry
 
     failures = []
 
@@ -705,8 +697,7 @@ def run_chaos(args, sec, out_dir="."):
         "fault_overhead_frac": frac,
         "failures": failures,
     }
-    merge_bench_json(os.path.join(out_dir, "BENCH_telemetry.json"),
-                     "fig3_chaos", payload)
+    print(json.dumps({"fig3_chaos": payload}, sort_keys=True))
     print("# fig3h: chaos-injected survival run (vtrace, socket, 2 hosts)")
     print("name,value,derived")
     print(f"fig3h_frames_per_s,{stats['env_frames_per_s']:.1f},"
@@ -780,10 +771,8 @@ def run_autoscale(args, sec, out_dir="."):
     - the armed-but-idle controller costs < 3% frames/s vs autoscale-off
       (in-proc best-of-N pair).
 
-    Appends ``{commit, frames_per_s}`` into ``BENCH_history.json`` (the
-    `check_trend.py` guard's input) and the full evidence payload into
-    ``BENCH_telemetry.json`` under ``fig3_autoscale``; exits nonzero on
-    any failed check (CI runs ``--smoke --autoscale`` under a hard
+    Prints the full evidence payload as one JSON line under
+    ``fig3_autoscale``; exits nonzero on any failed check (CI runs ``--smoke --autoscale`` under a hard
     timeout).
     """
     import functools
@@ -795,8 +784,7 @@ def run_autoscale(args, sec, out_dir="."):
     from repro.envs.alesim import FlatSimEnv
     from repro.onpolicy import VTraceLearner, mlp_actor_critic
     from repro.optim import adamw
-    from repro.telemetry import (Telemetry, append_bench_history,
-                                 bench_commit, merge_bench_json)
+    from repro.telemetry import Telemetry
 
     failures = []
 
@@ -934,13 +922,7 @@ def run_autoscale(args, sec, out_dir="."):
         "autoscale_overhead_frac": frac,
         "failures": failures,
     }
-    merge_bench_json(os.path.join(out_dir, "BENCH_telemetry.json"),
-                     "fig3_autoscale", payload)
-    append_bench_history(
-        os.path.join(out_dir, "BENCH_history.json"), "fig3_autoscale",
-        {"commit": bench_commit(), "ts": time.time(),
-         "frames_per_s": stats["env_frames_per_s"],
-         "smoke": bool(args.smoke)})
+    print(json.dumps({"fig3_autoscale": payload}, sort_keys=True))
 
     print("# fig3i: closed-loop autoscaler (vtrace, socket, actor-bound)")
     print("name,value,derived")
@@ -991,8 +973,7 @@ def main():
                          "conserved ledger and armed-idle overhead")
     ap.add_argument("--out-dir", default=".",
                     help="where --telemetry/--chaos/--autoscale write "
-                         "trace.json, metrics.jsonl, BENCH_telemetry.json "
-                         "and BENCH_history.json")
+                         "trace.json, metrics.jsonl and postmortems")
     args = ap.parse_args()
     sec = 0.3 if args.smoke else 1.2
     if args.telemetry:

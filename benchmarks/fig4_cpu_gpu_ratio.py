@@ -204,8 +204,8 @@ def main():
     ap.add_argument("--telemetry", action="store_true",
                     help="run each transport point under the telemetry "
                          "plane: print the MEASURED bottleneck/CPU-GPU "
-                         "ratio per transport and merge the attributions "
-                         "into BENCH_telemetry.json next to --out")
+                         "ratio per transport and print the attributions "
+                         "as one JSON line")
     args = ap.parse_args()
     sec = 0.5 if args.smoke else 1.5
     hosts = max(1 if args.smoke else 2, args.gateways)
@@ -280,16 +280,12 @@ def main():
                   f"{b_['cpu_gpu_ratio']:.2f},{b_['bottleneck']} "
                   f"wire_share={b_['shares'].get('wire', 0.0):.2f}")
     if args.telemetry:
-        from repro.telemetry import merge_bench_json
-        tel_out = os.path.join(os.path.dirname(os.path.normpath(args.out)),
-                               "BENCH_telemetry.json")
-        merge_bench_json(tel_out, "fig4_transports", {
+        print(json.dumps({"fig4_transports": {
             "smoke": bool(args.smoke), "seconds": sec,
             "num_actors": n_act, "envs_per_actor": E,
             "attribution": {t: s["bottleneck"] for t, s in t_rows
                             if "bottleneck" in s},
-        })
-        print(f"# merged measured attributions into {tel_out}")
+        }}, sort_keys=True))
     gate_failed = None
     if min(fps.values()) <= 0:
         # a failed run reports its error above; don't bury it under a
@@ -347,17 +343,6 @@ def main():
         json.dump(bench, f, indent=2, sort_keys=True)
         f.write("\n")
     print(f"# wrote {out}")
-    # trend-guard history: one point per wire transport measured this run
-    # (BENCH_wire.json above is wholesale-replaced; the history ledger
-    # accumulates — see benchmarks/check_trend.py)
-    from repro.telemetry import append_bench_history, bench_commit
-    hist_path = os.path.join(os.path.dirname(out), "BENCH_history.json")
-    for wire_t in wire_transports[1:]:
-        if fps.get(wire_t, 0) > 0:
-            append_bench_history(
-                hist_path, f"fig4_{wire_t}",
-                {"commit": bench_commit(), "ts": time.time(),
-                 "frames_per_s": fps[wire_t], "smoke": bool(args.smoke)})
     if gate_failed and "shm" in wire_transports:
         print(f"fig4_shm_gate,FAIL,{gate_failed}")
         sys.exit(1)

@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.core.inference import ReplyError
 from repro.envs.vector import make_vector_env
-from repro.telemetry.tracer import next_trace_seq
+from repro.telemetry.tracer import maybe_span, next_trace_seq
 
 
 # canonical per-lane dtypes; keys outside this map pass through unchanged
@@ -232,7 +232,8 @@ class Actor:
                     break
                 logprobs = actions[:, 1].astype(np.float32)
                 actions = actions[:, 0].astype(np.int32)
-            nobs, rewards, dones = self.vec.step(actions)
+            with maybe_span(tr, "actor/env_step"):
+                nobs, rewards, dones = self.vec.step(actions)
             self.iterations += 1
             self.frames += E
             buf["obs"].append(obs)

@@ -49,6 +49,7 @@ from typing import Callable, Dict, Optional, Tuple
 import numpy as np
 
 from repro.telemetry.metrics import MetricsRegistry
+from repro.telemetry.tracer import maybe_span
 
 
 @dataclass
@@ -159,36 +160,40 @@ class _Replica:
         srv = self.server
         hb = srv._health
         hb_name = f"inference/replica{self.replica_id}"
+        tr = srv._tracer
         while not srv._stop.is_set():
             if hb is not None:
                 hb.beat(hb_name)
             batch = self._collect()
             if not batch:
                 continue
-            t0 = time.perf_counter()
-            try:
-                obs = np.concatenate([r.obs for r in batch])  # (N_lanes, ...)
-                ids = np.concatenate(
-                    [srv.slot_ids(r.actor_id, r.lanes) for r in batch])
-                actions = np.asarray(srv.policy_step(obs, ids))
-            except Exception:
-                # poison the IN-FLIGHT batch too, not just the queues: these
-                # requests were already popped by _collect, and for wire
-                # transports the poison is the only signal the remote actor
-                # will ever receive (it cannot read this server's .error)
-                err = traceback.format_exc()
+            with maybe_span(tr, "inference/forward"):
+                t0 = time.perf_counter()
+                try:
+                    # (N_lanes, ...)
+                    obs = np.concatenate([r.obs for r in batch])
+                    ids = np.concatenate(
+                        [srv.slot_ids(r.actor_id, r.lanes) for r in batch])
+                    actions = np.asarray(srv.policy_step(obs, ids))
+                except Exception:
+                    # poison the IN-FLIGHT batch too, not just the queues:
+                    # these requests were already popped by _collect, and
+                    # for wire transports the poison is the only signal
+                    # the remote actor will ever receive (it cannot read
+                    # this server's .error)
+                    err = traceback.format_exc()
+                    for r in batch:
+                        r.reply.put(ReplyError(err))
+                    srv._fatal(err)
+                    return
+                dt = time.perf_counter() - t0
+                lanes = 0
+                waits = []
                 for r in batch:
-                    r.reply.put(ReplyError(err))
-                srv._fatal(err)
-                return
-            dt = time.perf_counter() - t0
-            lanes = 0
-            waits = []
-            for r in batch:
-                a = actions[lanes:lanes + r.lanes]
-                lanes += r.lanes
-                r.reply.put(a[0] if r.scalar else a)
-                waits.append(t0 - r.t_enqueue)
+                    a = actions[lanes:lanes + r.lanes]
+                    lanes += r.lanes
+                    r.reply.put(a[0] if r.scalar else a)
+                    waits.append(t0 - r.t_enqueue)
             # ONE lock acquisition per batch: counters + histograms move
             # together, so no snapshot can see a batch counted without its
             # requests (or a wait histogram ahead of its rpc count)
@@ -205,7 +210,6 @@ class _Replica:
                 for w in waits:
                     srv._h_wait.record_locked(max(w, 0.0))
                 srv._h_compute.record_locked(dt)
-            tr = srv._tracer
             if tr is not None:
                 t1_ns = time.perf_counter_ns()
                 t0_ns = t1_ns - int(dt * 1e9)

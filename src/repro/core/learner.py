@@ -4,7 +4,9 @@ The learner is the accelerator-resident half of SEED: it consumes
 trajectory batches (prioritized replay for R2D2, on-policy queue for
 V-trace), runs the jitted/pjitted train_step, and publishes fresh params
 to the inference server under a version counter. Periodic checkpointing
-and restart-on-failure live here (see repro.checkpoint)."""
+and restart-on-failure live here (see repro.checkpoint). Always-on
+counters time each step's parts; with telemetry each part is also a
+``learner/*`` span."""
 
 import queue
 import threading
@@ -14,6 +16,8 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import numpy as np
+
+from repro.telemetry.tracer import maybe_span
 
 
 class BatchSourceClosed(Exception):
@@ -56,11 +60,15 @@ class Learner:
         self._thread: Optional[threading.Thread] = None
         self.steps = 0
         self.metrics: Dict[str, float] = {}
-        self.train_time_s = 0.0
+        # always-on counters: seconds waiting for a batch, training on it
+        # (its transfer to the device, inside the train step's call,
+        # included) and after the step (see _post_step)
         self.wait_time_s = 0.0
+        self.train_time_s = 0.0
+        self.post_time_s = 0.0
         self.error: Optional[str] = None     # traceback of a fatal loop error
-        # timings are already taken in _one_step; telemetry just adds the
-        # distribution (p50/p95/p99) view and an optional per-step span
+        # timings are already taken in _one_step; telemetry adds the
+        # distribution (p50/p95/p99) view and the spans of each step
         self._tracer = (telemetry.tracer
                         if telemetry is not None and telemetry.enabled
                         else None)
@@ -96,36 +104,51 @@ class Learner:
             self._one_step()
 
     def _one_step(self):
-        t0 = time.perf_counter()
-        batch, info = self.batch_fn()
-        t1 = time.perf_counter()
-        self.state, metrics = self.train_step(self.state, batch)
-        jax.block_until_ready(self.state["step"])
-        t2 = time.perf_counter()
-        self.wait_time_s += t1 - t0
-        self.train_time_s += t2 - t1
-        self.steps += 1
-        if self._h_train is not None:
-            self._h_wait.record(t1 - t0)
-            self._h_train.record(t2 - t1)
-        if self._tracer is not None:
-            now_ns = time.perf_counter_ns()
-            self._tracer.record("learner/train_step",
-                                now_ns - int((t2 - t1) * 1e9),
-                                int((t2 - t1) * 1e9),
-                                args={"step": self.steps})
-        self.metrics = {k: float(np.asarray(v).mean()) for k, v in metrics.items()
-                        if np.asarray(v).ndim == 0}
+        tr = self._tracer
+        with maybe_span(tr, "learner/step"):
+            t0 = time.perf_counter()
+            with maybe_span(tr, "learner/batch"):
+                batch, info = self.batch_fn()
+            t1 = time.perf_counter()
+            with maybe_span(tr, "learner/train"):
+                self.state, metrics = self.train_step(self.state, batch)
+                jax.block_until_ready(self.state["step"])
+            t2 = time.perf_counter()
+            self.wait_time_s += t1 - t0
+            self.train_time_s += t2 - t1
+            self.steps += 1
+            if self._h_train is not None:
+                self._h_wait.record(t1 - t0)
+                self._h_train.record(t2 - t1)
+            with maybe_span(tr, "learner/post"):
+                self._post_step(tr, metrics, info)
+                # the step's last references: freeing them is part of
+                # the iteration, not of the time between two of them
+                del batch, metrics, info
+            self.post_time_s += time.perf_counter() - t2
+
+    def _post_step(self, tr, metrics, info):
+        """What follows a train step: the scalar metrics pulled to the
+        host, the replay priorities, the publish and the checkpoint."""
+        with maybe_span(tr, "learner/metrics_pull"):
+            self.metrics = {k: float(np.asarray(v).mean())
+                            for k, v in metrics.items()
+                            if np.asarray(v).ndim == 0}
         if self.priority_update and "priorities" in metrics:
-            self.priority_update(info, np.asarray(metrics["priorities"]))
+            with maybe_span(tr, "learner/priority_update"):
+                self.priority_update(info, np.asarray(metrics["priorities"]))
         if self.publish:
-            self.publish(self.state["params"], self.steps)
-        if self.ckpt and self.checkpoint_every and \
-                self.steps % self.checkpoint_every == 0:
+            with maybe_span(tr, "learner/publish"):
+                self.publish(self.state["params"], self.steps)
+        if self.ckpt:
+            with maybe_span(tr, "learner/checkpoint"):
+                self._maybe_checkpoint()
+
+    def _maybe_checkpoint(self):
+        if self.checkpoint_every and self.steps % self.checkpoint_every == 0:
             self.ckpt.save(self.state, self.steps)
-        elif self.ckpt and self.checkpoint_every_s and \
-                time.perf_counter() - self._last_ckpt_t \
-                >= self.checkpoint_every_s:
+        elif self.checkpoint_every_s and time.perf_counter() \
+                - self._last_ckpt_t >= self.checkpoint_every_s:
             # async: hands off a host snapshot and keeps training — the
             # save must not stall the accelerator (see CheckpointManager)
             self.ckpt.save(self.state, self.steps)

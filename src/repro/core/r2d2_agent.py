@@ -18,6 +18,7 @@ from repro.core.system import SeedSystem
 from repro.models.atari import make_atari
 from repro.nn.recurrent import lstm_state_init
 from repro.optim import adamw
+from repro.telemetry.tracer import maybe_span
 
 LR = 5e-4
 EPSILON = 0.2          # share of actions drawn uniformly at random
@@ -27,7 +28,8 @@ SEED = 0
 
 def build_r2d2_system(acfg, env_factory, *, num_actors: int,
                       envs_per_actor: int, learner_batch: int,
-                      replay_capacity: int, min_replay: int) -> SeedSystem:
+                      replay_capacity: int, min_replay: int,
+                      telemetry=None) -> SeedSystem:
     """A host-backend, in-process `SeedSystem` training R2D2 on `acfg`.
 
     Both jitted paths are compiled before this returns, so a `run()`
@@ -35,7 +37,15 @@ def build_r2d2_system(acfg, env_factory, *, num_actors: int,
     batch (whole actors: ``k * envs_per_actor`` for k = 1..``num_actors``)
     and the train step at ``learner_batch`` sequences of ``burn_in +
     unroll`` steps. The compile leaves the train and LSTM state untouched.
+
+    ``telemetry`` (a `repro.telemetry.Telemetry`) goes to the system; with
+    it enabled, each policy step is traced as its ``policy/slot_gather``,
+    ``policy/dispatch``, ``policy/fetch`` and ``policy/slot_scatter``, and
+    the batch's conversion to device arrays inside each train step as
+    ``learner/input``.
     """
+    tr = (telemetry.tracer
+          if telemetry is not None and telemetry.enabled else None)
     bundle = make_atari(acfg)
     opt = adamw(LR)
     state = init_train_state(bundle, opt, jax.random.PRNGKey(SEED),
@@ -62,22 +72,28 @@ def build_r2d2_system(acfg, env_factory, *, num_actors: int,
         n = len(ids)
         with params_lock:
             p = live["params"]
-        a, h2, c2 = _policy(p, obs, core["h"][ids], core["c"][ids])
-        core["h"][ids] = np.asarray(h2)
-        core["c"][ids] = np.asarray(c2)
+        with maybe_span(tr, "policy/slot_gather"):
+            h, c = core["h"][ids], core["c"][ids]
+        with maybe_span(tr, "policy/dispatch"):
+            a, h2, c2 = _policy(p, obs, h, c)
+        with maybe_span(tr, "policy/fetch"):
+            a, h2, c2 = np.asarray(a), np.asarray(h2), np.asarray(c2)
+        with maybe_span(tr, "policy/slot_scatter"):
+            core["h"][ids] = h2
+            core["c"][ids] = c2
         explore = rng.random(n) < EPSILON
-        return np.where(explore, rng.integers(0, acfg.num_actions, n),
-                        np.asarray(a))
+        return np.where(explore, rng.integers(0, acfg.num_actions, n), a)
 
     def learner_step(st, batch):
         b = batch["obs"].shape[0]
-        jb = {
-            "obs": jnp.asarray(batch["obs"]),
-            "actions": jnp.asarray(batch["actions"], jnp.int32),
-            "rewards": jnp.asarray(batch["rewards"]),
-            "dones": jnp.asarray(batch["dones"]),
-            "core": lstm_state_init(b, acfg.core_dim),
-        }
+        with maybe_span(tr, "learner/input"):
+            jb = {
+                "obs": jnp.asarray(batch["obs"]),
+                "actions": jnp.asarray(batch["actions"], jnp.int32),
+                "rewards": jnp.asarray(batch["rewards"]),
+                "dones": jnp.asarray(batch["dones"]),
+                "core": lstm_state_init(b, acfg.core_dim),
+            }
         st, metrics = train_step(st, jb)
         with params_lock:
             live["params"] = st["params"]
@@ -104,4 +120,4 @@ def build_r2d2_system(acfg, env_factory, *, num_actors: int,
         num_actors=num_actors, unroll=seq_len, envs_per_actor=envs_per_actor,
         train_step=learner_step, state=state, learner_batch=learner_batch,
         replay_capacity=replay_capacity, min_replay=min_replay,
-        deadline_ms=DEADLINE_MS)
+        deadline_ms=DEADLINE_MS, telemetry=telemetry)

@@ -236,9 +236,12 @@ class SeedSystem:
         self.transport = transport
         self.algo = algo
         self.telemetry = telemetry
+        # the span recorder the planes below share; None keeps them off
+        tracer = (telemetry.tracer
+                  if telemetry is not None and telemetry.enabled else None)
         self.envs_per_actor = envs_per_actor
         self.engine_shards = engine_shards
-        self.replay = PrioritizedReplay(replay_capacity)
+        self.replay = PrioritizedReplay(replay_capacity, tracer=tracer)
         self.min_replay = min_replay
         self.learner_batch = learner_batch
         self._policy_publish = policy_publish
@@ -276,6 +279,10 @@ class SeedSystem:
         if backend == "host":
             if policy_step is None:
                 raise ValueError("backend='host' requires policy_step")
+            if tracer is not None and hasattr(policy_step, "tracer"):
+                # a policy step that traces its own parts
+                # (onpolicy.SamplingPolicy) records into this system's ring
+                policy_step.tracer = tracer
             # raises ValueError when num_replicas exceeds the lane budget
             self.server = InferenceServer(
                 policy_step,
@@ -364,14 +371,14 @@ class SeedSystem:
             self.actors = [
                 RolloutWorker(i, make_engine(i), self._sink,
                               self._param_source, stamp_records=onpolicy,
-                              health=self._health)
+                              health=self._health, tracer=tracer)
                 for i in range(num_actors)]
         self.learner = None
         if train_step is not None:
             if onpolicy:
                 from repro.onpolicy import VTraceBatcher
                 batch_fn = VTraceBatcher(self.onpolicy_queue, learner_batch,
-                                         gamma=gamma)
+                                         gamma=gamma, tracer=tracer)
                 poison = self.onpolicy_queue.close
                 priority_update = None
             else:
@@ -481,6 +488,26 @@ class SeedSystem:
                                            for s in self.pool.last_stats)
         return out
 
+    def _timing_stats(self) -> dict:
+        """The planes' always-on time counters, summed since construction
+        — shared by `throughput()["timings"]` and the `/metrics`
+        collector. A stable key set, zero where a plane is off; a mean is
+        a difference of seconds over a difference of its count (learner
+        steps, replay samples or adds, `actor_iterations`)."""
+        ln = self.learner
+        rp = self.replay
+        return {
+            "learner_wait_s": ln.wait_time_s if ln else 0.0,
+            "learner_train_s": ln.train_time_s if ln else 0.0,
+            "learner_post_s": ln.post_time_s if ln else 0.0,
+            "replay_samples": rp.samples,
+            "replay_sample_s": rp.sample_time_s,
+            "replay_adds": rp.adds,
+            "replay_add_wait_s": rp.add_wait_s,
+            "rollout_flush_s": sum(getattr(a, "flush_time_s", 0.0)
+                                   for a in self.actors),
+        }
+
     def resume(self) -> int:
         """Learner crash recovery: restore the latest checkpoint into the
         live loop and make the system runnable again. Returns the version
@@ -541,6 +568,8 @@ class SeedSystem:
             out["inference/num_slots"] = self.server.num_slots
         for k, v in self._recovery_stats().items():
             out[f"recovery/{k}"] = v
+        for k, v in self._timing_stats().items():
+            out[f"timings/{k}"] = v
         return out
 
     def _autoscale_stats(self) -> dict:
@@ -842,6 +871,7 @@ class SeedSystem:
         out["onpolicy"] = (self.onpolicy_queue.stats()
                            if self.onpolicy_queue is not None
                            else dict(ZERO_LEDGER))
+        out["timings"] = self._timing_stats()
         # survival counters: how much dying/reconnecting/checkpointing the
         # run absorbed (all zero on a calm run — the overhead gate's claim)
         out["recovery"] = self._recovery_stats()

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core.learner import BatchSourceClosed
 from repro.onpolicy.queue import Closed, TrajectoryQueue
+from repro.telemetry.tracer import maybe_span
 
 
 def assemble_vtrace_batch(unrolls: List[Dict[str, np.ndarray]],
@@ -52,23 +53,28 @@ class VTraceBatcher:
     ``batcher() -> (batch, None)`` blocks until `batch_size` unrolls are
     available; a closed queue surfaces as `BatchSourceClosed`, which
     `Learner._loop` treats as a clean shutdown (the poison seam — see
-    `Learner.stop`).
+    `Learner.stop`). With a tracer, the pop and the assembly are the
+    ``onpolicy/pop_batch`` and ``onpolicy/assemble`` spans.
     """
 
     def __init__(self, queue: TrajectoryQueue, batch_size: int,
                  gamma: float = 0.99,
-                 poll_timeout_s: Optional[float] = 0.5):
+                 poll_timeout_s: Optional[float] = 0.5, tracer=None):
         self.queue = queue
         self.batch_size = batch_size
         self.gamma = gamma
         self.poll_timeout_s = poll_timeout_s
+        self._tracer = tracer
 
     def __call__(self):
+        tr = self._tracer
         while True:
             try:
-                unrolls = self.queue.pop_batch(self.batch_size,
-                                               timeout=self.poll_timeout_s)
-                return assemble_vtrace_batch(unrolls, self.gamma), None
+                with maybe_span(tr, "onpolicy/pop_batch"):
+                    unrolls = self.queue.pop_batch(
+                        self.batch_size, timeout=self.poll_timeout_s)
+                with maybe_span(tr, "onpolicy/assemble"):
+                    return assemble_vtrace_batch(unrolls, self.gamma), None
             except Closed:
                 raise BatchSourceClosed("trajectory queue closed") from None
             except TimeoutError:

@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.core.vtrace import vtrace, vtrace_losses
 from repro.optim.adamw import apply_updates
+from repro.telemetry.tracer import maybe_span
 
 
 def mlp_actor_critic(obs_dim: int, num_actions: int, hidden: int = 64):
@@ -171,7 +172,9 @@ class SamplingPolicy:
     via `publish` (wire it as `SeedSystem(policy_publish=...)`), under a
     lock because inference replicas may call concurrently with the
     learner's publish; `version` mirrors the publish step so callers can
-    expose it (the gateway stamps it onto wire replies).
+    expose it (the gateway stamps it onto wire replies). A `SeedSystem`
+    with telemetry sets ``tracer`` to its own, and each call is then
+    traced as ``policy/key``, ``policy/dispatch`` and ``policy/fetch``.
     """
 
     def __init__(self, apply_fn: Callable, params, seed: int = 0):
@@ -180,6 +183,7 @@ class SamplingPolicy:
         self._params = params
         self._base_key = jax.random.PRNGKey(seed)
         self._calls = 0
+        self.tracer = None
         self.version = 0
 
     def publish(self, params, step: int):
@@ -188,14 +192,18 @@ class SamplingPolicy:
             self.version = int(step)
 
     def __call__(self, obs: np.ndarray, slot_ids) -> np.ndarray:
-        with self._lock:
-            params = self._params
-            self._calls += 1
-            key = jax.random.fold_in(self._base_key, self._calls)
-        actions, lp = self._sample(params, jnp.asarray(obs), key)
-        out = np.empty((np.asarray(obs).shape[0], 2), np.float32)
-        out[:, 0] = np.asarray(actions)
-        out[:, 1] = np.asarray(lp)
+        tr = self.tracer
+        with maybe_span(tr, "policy/key"):
+            with self._lock:
+                params = self._params
+                self._calls += 1
+                key = jax.random.fold_in(self._base_key, self._calls)
+        with maybe_span(tr, "policy/dispatch"):
+            actions, lp = self._sample(params, jnp.asarray(obs), key)
+        with maybe_span(tr, "policy/fetch"):
+            out = np.empty((np.asarray(obs).shape[0], 2), np.float32)
+            out[:, 0] = np.asarray(actions)
+            out[:, 1] = np.asarray(lp)
         return out
 
 

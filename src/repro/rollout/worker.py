@@ -10,23 +10,28 @@ the previous scan were published.
 """
 
 import threading
+import time
 import traceback
 from typing import Callable, Optional
 
 import numpy as np
 
 from repro.core.actor import account_episode_ends, flush_lane_unrolls
+from repro.telemetry.tracer import maybe_span
 
 
 class RolloutWorker:
     def __init__(self, worker_id: int, engine, sink: Callable,
                  param_source: Callable, stamp_records: bool = False,
-                 health=None):
+                 health=None, tracer=None):
         """param_source() -> (params, version): latest published params and
         a monotone version counter (learner steps; 0 before any publish).
         ``stamp_records=True`` writes the behavior ``param_version`` into
         every flushed lane record — the on-policy queue's admission key
-        (replay records stay byte-identical without it)."""
+        (replay records stay byte-identical without it). With a
+        ``tracer``, each scan is a ``rollout/scan`` span (dispatch to the
+        trajectory on the host) and a ``rollout/flush`` span (episode
+        accounting and the unrolls handed to the sink)."""
         self.worker_id = worker_id
         self.engine = engine
         self.sink = sink
@@ -40,8 +45,10 @@ class RolloutWorker:
         self.param_version = 0            # version driving the current scan
         self.param_refreshes = 0          # scans that picked up fresh params
         self.param_lag_total = 0          # sum of version deltas across scans
+        self.flush_time_s = 0.0           # trajectory on host -> sink done
         self.error: Optional[str] = None
         self._health = health             # optional HeartbeatRegistry
+        self._tracer = tracer
 
     # the engine is the single source of truth for scan/frame counts
     @property
@@ -91,6 +98,7 @@ class RolloutWorker:
     def _run(self):
         T = self.engine.unroll
         hb = self._health
+        tr = self._tracer
         hb_name = f"rollout/worker{self.worker_id}"
         while not self._stop.is_set():
             if hb is not None:
@@ -100,11 +108,16 @@ class RolloutWorker:
                 self.param_lag_total += version - self.param_version
                 self.param_refreshes += 1
                 self.param_version = version
-            traj = self.engine.rollout(params)          # (T, E, ...)
-            rewards, dones = traj["rewards"], traj["dones"].astype(bool)
-            for t in range(T):
-                self.episodes += account_episode_ends(
-                    rewards[t], dones[t], self.episode_returns, self.returns)
-            extra = ({"param_version": np.int64(self.param_version)}
-                     if self.stamp_records else None)
-            flush_lane_unrolls(traj, self.sink, extra=extra)
+            with maybe_span(tr, "rollout/scan"):
+                traj = self.engine.rollout(params)      # (T, E, ...)
+            t0 = time.perf_counter()
+            with maybe_span(tr, "rollout/flush"):
+                rewards, dones = traj["rewards"], traj["dones"].astype(bool)
+                for t in range(T):
+                    self.episodes += account_episode_ends(
+                        rewards[t], dones[t], self.episode_returns,
+                        self.returns)
+                extra = ({"param_version": np.int64(self.param_version)}
+                         if self.stamp_records else None)
+                flush_lane_unrolls(traj, self.sink, extra=extra)
+            self.flush_time_s += time.perf_counter() - t0

@@ -13,9 +13,11 @@ Instrument      Question it answers                    Overhead
 `Tracer`        *When/where did THIS request go?*      disabled: one attr
 (spans)         Per-event timelines, cross-process     check returning a
                 stitching by wire trace_seq, Perfetto  cached no-op span;
-                visualization. Bounded ring: keeps     enabled: 2 clock
-                the newest window, drops the oldest.   reads + a GIL-atomic
-                                                       deque append/span.
+                visualization, and the jax profiler's  enabled: 2 clock
+                host plane beside the device's ops.    reads, a profiler
+                Bounded ring: keeps the newest         annotation and a
+                window, drops the oldest.              GIL-atomic deque
+                                                       append/span.
 `MetricsRegistry` *How is the system doing overall?*   one shared lock per
 (counters/      Totals, rates, occupancy, queue        update or batched
 gauges/         depths, p50/p95/p99 latency            update group; hot
@@ -73,8 +75,7 @@ from .ops import (OpsServer, parse_prometheus, render_prometheus,
                   sanitize_metric_name, validate_prometheus)
 from .sampler import (BottleneckReport, UtilizationSampler,
                       attribute_bottleneck, read_process_cpu_s)
-from .sink import (TelemetrySink, append_bench_history, bench_commit,
-                   merge_bench_json)
+from .sink import TelemetrySink
 from .slo import SLO, SLOSet, SLOVerdict
 from .timeseries import TimeSeries, TimeSeriesStore
 from .tracer import Tracer, chrome_trace, flow_events, next_trace_seq
@@ -83,7 +84,6 @@ __all__ = [
     "Telemetry", "Tracer", "MetricsRegistry", "Counter", "Gauge",
     "Histogram", "UtilizationSampler", "BottleneckReport",
     "attribute_bottleneck", "read_process_cpu_s", "TelemetrySink",
-    "merge_bench_json", "append_bench_history", "bench_commit",
     "next_trace_seq", "flow_events", "chrome_trace",
     "HeartbeatRegistry", "HealthReport", "Watchdog", "FlightRecorder",
     "InvariantAuditor", "OpsServer", "render_prometheus",

@@ -15,19 +15,13 @@ flight recorder triggering while a dump is in flight, a SIGKILL'd CI
 job) can never leave a truncated trace.json that Perfetto rejects or a
 half-line in metrics.jsonl. Readers either see the previous complete
 artifact or the new complete one.
-
-`merge_bench_json` is the fig3/fig4 helper: both benchmarks append their
-measured section into ONE ``BENCH_telemetry.json`` keyed by benchmark
-name, so re-running either refreshes its own section without clobbering
-the other's.
 """
 
 import json
 import os
 from typing import Callable, Dict, List, Optional
 
-__all__ = ["TelemetrySink", "merge_bench_json", "append_bench_history",
-           "bench_commit", "METRICS_SCHEMA_VERSION"]
+__all__ = ["TelemetrySink", "METRICS_SCHEMA_VERSION"]
 
 # bump when the shape of a metrics.jsonl line changes; consumers key
 # their parsing on the per-line "schema" stamp
@@ -71,76 +65,3 @@ class TelemetrySink:
 
         _atomic_write(metrics_path, _write_lines)
         return {"trace": trace_path, "metrics": metrics_path}
-
-
-def merge_bench_json(path: str, key: str, payload: dict) -> dict:
-    """Read-modify-write ``path`` setting ``doc[key] = payload``."""
-    doc = {}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            doc = {}
-    if not isinstance(doc, dict):
-        doc = {}
-    doc[key] = payload
-
-    def _write(f):
-        json.dump(doc, f, indent=2, sort_keys=True)
-        f.write("\n")
-
-    _atomic_write(path, _write)
-    return doc
-
-
-def bench_commit() -> str:
-    """Best-effort commit id for bench history entries: the checkout's
-    HEAD, else the CI-provided sha, else 'unknown' (never raises)."""
-    sha = os.environ.get("GITHUB_SHA", "")
-    try:
-        import subprocess
-        out = subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"],
-            capture_output=True, text=True, timeout=5.0,
-            cwd=os.path.dirname(os.path.abspath(__file__)))
-        if out.returncode == 0 and out.stdout.strip():
-            return out.stdout.strip()
-    except Exception:
-        pass
-    return sha[:12] if sha else "unknown"
-
-
-def append_bench_history(path: str, key: str, entry: dict,
-                         keep: int = 50) -> list:
-    """Append one measured point to ``doc[key]`` (a list) in the shared
-    bench-history ledger, keeping the last ``keep`` entries.
-
-    This is the trend guard's data source (`benchmarks/check_trend.py`):
-    each fig3/fig4 run appends ``{"commit", "ts", "frames_per_s", ...}``
-    so a throughput regression shows up as a comparable series, not a
-    silent drift. The file is separate from the `merge_bench_json`
-    sections (which are wholesale-replaced per run) precisely so history
-    survives re-runs."""
-    doc = {}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            doc = {}
-    if not isinstance(doc, dict):
-        doc = {}
-    hist = doc.get(key)
-    if not isinstance(hist, list):
-        hist = []
-    hist.append(dict(entry))
-    hist = hist[-max(int(keep), 1):]
-    doc[key] = hist
-
-    def _write(f):
-        json.dump(doc, f, indent=2, sort_keys=True)
-        f.write("\n")
-
-    _atomic_write(path, _write)
-    return hist

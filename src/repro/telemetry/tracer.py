@@ -12,7 +12,14 @@ Design constraints, in priority order:
    the GIL); the tracer's lock is only taken once per thread (ring
    registration) and at export. Wraparound silently drops the OLDEST
    spans — tracing is a window, not a ledger.
-3. **Cross-process stitching.** Timestamps come from
+3. **One span, two sinks.** An enabled `trace_span()` also enters
+   `jax.profiler.TraceAnnotation(name)`, so while a profiler session
+   runs the span lands on the profiler's host plane under its own name,
+   on the clock the device's ``XLA Ops`` use: idle device time can be
+   set against what the host was doing. With no session it costs well
+   under a microsecond. Spans recorded after the fact (`record`,
+   `begin`/`end`) stay in the ring only: the profiler cannot backdate.
+4. **Cross-process stitching.** Timestamps come from
    `time.perf_counter_ns()` — CLOCK_MONOTONIC on Linux, one timebase for
    every process on the host — so spans recorded in spawned actor-host
    processes line up with learner-side spans on one Perfetto timeline.
@@ -35,7 +42,10 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["Tracer", "next_trace_seq", "flow_events", "chrome_trace"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "maybe_span", "next_trace_seq", "flow_events",
+           "chrome_trace"]
 
 _now_ns = time.perf_counter_ns
 
@@ -66,8 +76,16 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def maybe_span(tracer: Optional["Tracer"], name: str):
+    """``tracer.trace_span(name)``, or the shared no-op span where the
+    instrumented object holds no tracer (telemetry off)."""
+    if tracer is None:
+        return _NULL_SPAN
+    return tracer.trace_span(name)
+
+
 class _Span:
-    __slots__ = ("_tracer", "_name", "_seq", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_seq", "_args", "_t0", "_ann")
 
     def __init__(self, tracer, name, seq, args):
         self._tracer = tracer
@@ -76,13 +94,16 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
         self._t0 = _now_ns()
         return self
 
     def __exit__(self, *exc):
         t0 = self._t0
-        self._tracer.record(self._name, t0, _now_ns() - t0, self._seq,
-                            self._args)
+        dur = _now_ns() - t0
+        self._ann.__exit__(*exc)
+        self._tracer.record(self._name, t0, dur, self._seq, self._args)
         return False
 
 
@@ -112,7 +133,8 @@ class Tracer:
     # ------------------------------------------------------------ recording
 
     def trace_span(self, name: str, seq: int = 0, args: Optional[dict] = None):
-        """Context manager timing one same-thread span."""
+        """Context manager timing one same-thread span, in the ring and,
+        while a profiler session runs, on the profiler's host plane."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, seq, args)

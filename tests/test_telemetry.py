@@ -443,3 +443,310 @@ def test_socket_system_cross_process_stitch(tmp_path):
     doc = json.load(open(tel.dump()["trace"]))
     pids = {e["pid"] for e in doc["traceEvents"]}
     assert len(pids) >= 2
+
+
+# ------------------------------------------- spans at the layer boundaries
+
+def _spans(tracer):
+    """{name: [(start_ns, end_ns, tid)]} of the ring's complete events."""
+    out = {}
+    for e in tracer.export_events():
+        if e["ph"] == "X":
+            t0 = e["ts"] * 1e3
+            out.setdefault(e["name"], []).append(
+                (t0, t0 + e["dur"] * 1e3, e["tid"]))
+    return out
+
+
+def _inside(child, parent):
+    return child[2] == parent[2] and parent[0] <= child[0] \
+        and child[1] <= parent[1]
+
+
+def test_enabled_span_reads_back_from_the_profilers_host_plane(tmp_path):
+    import glob
+
+    import jax
+
+    tr = Tracer(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.trace_span("learner/step"):
+            with tr.trace_span("learner/train"):
+                jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("learner/"):
+                        found[e.name] = (e.start_ns,
+                                         e.start_ns + e.duration_ns)
+    assert set(found) == {"learner/step", "learner/train"}
+    step, train = found["learner/step"], found["learner/train"]
+    assert step[0] <= train[0] and train[1] <= step[1]
+    # the ring keeps its record as before
+    assert set(_spans(tr)) == {"learner/step", "learner/train"}
+
+
+def test_disabled_tracer_records_nothing_and_returns_the_null_span(
+        monkeypatch):
+    from repro.telemetry import tracer as tracer_mod
+
+    def no_jax(name):
+        raise AssertionError("the disabled path called the profiler")
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", no_jax)
+    tr = Tracer(enabled=False)
+    assert tr.trace_span("learner/step") is tracer_mod._NULL_SPAN
+    assert tracer_mod.maybe_span(None, "x") is tracer_mod._NULL_SPAN
+    with tr.trace_span("learner/step"), tracer_mod.maybe_span(None, "x"):
+        pass
+    assert tr.span_count() == 0
+
+
+class _Batches:
+    def __init__(self, n=4):
+        self.n = n
+
+    def __call__(self):
+        return ({"obs": np.ones((self.n, 3), np.float32),
+                 "ids": np.arange(self.n, dtype=np.int64)},
+                np.arange(self.n))
+
+
+def _fake_train_step(seen):
+    import jax
+
+    @jax.jit
+    def step(state, batch):
+        loss = batch["obs"].sum() + batch["ids"].sum()
+        return ({"step": state["step"] + 1,
+                 "params": state["params"] + loss},
+                {"loss": loss, "priorities": batch["obs"][:, 0]})
+
+    def train_step(state, batch):
+        seen.append(batch)
+        return step(state, batch)
+    return train_step
+
+
+def test_learner_fills_the_post_counter():
+    from repro.core.learner import Learner
+
+    seen, published, prios = [], [], []
+
+    def publish(params, step):
+        time.sleep(0.01)
+        published.append(step)
+
+    ln = Learner(_fake_train_step(seen),
+                 {"step": np.zeros((), np.int32), "params": np.zeros(())},
+                 _Batches(), publish=publish,
+                 priority_update=lambda idx, p: prios.append((idx, p)))
+    t0 = time.perf_counter()
+    ln.run_steps(3)
+    wall = time.perf_counter() - t0
+    assert ln.steps == 3 and published == [1, 2, 3] and len(prios) == 3
+    assert ln.post_time_s >= 0.03              # three 10 ms publishes
+    # the three counters cover the iterations, their release included
+    counted = ln.wait_time_s + ln.train_time_s + ln.post_time_s
+    assert 0 <= wall - counted < 0.01
+    assert ln.wait_time_s > 0 and ln.train_time_s > 0
+    assert ln.metrics["loss"] == pytest.approx(12.0 + 6.0)
+    # the step gets the batch as the batch source made it: its transfer
+    # is the jitted call's, inside the train interval
+    assert all(isinstance(v, np.ndarray) for v in seen[0].values())
+
+
+def test_learner_spans_nest_under_the_step():
+    from repro.core.learner import Learner
+
+    tel = Telemetry(process_name="learner")
+    ln = Learner(_fake_train_step([]),
+                 {"step": np.zeros((), np.int32), "params": np.zeros(())},
+                 _Batches(), publish=lambda p, s: None,
+                 priority_update=lambda idx, p: None, telemetry=tel)
+    ln.run_steps(2)
+    spans = _spans(tel.tracer)
+    children = ("learner/batch", "learner/train", "learner/post")
+    post_children = ("learner/metrics_pull", "learner/priority_update",
+                     "learner/publish")
+    assert set(spans) == {"learner/step", *children, *post_children}
+    assert len(spans["learner/step"]) == 2
+    for i, step in enumerate(spans["learner/step"]):
+        for name in children:
+            assert _inside(spans[name][i], step), name
+        for name in post_children:
+            assert _inside(spans[name][i], spans["learner/post"][i]), name
+
+
+def test_replay_counts_samples_adds_and_the_adds_lock_wait():
+    from repro.core.replay import PrioritizedReplay
+
+    buf = PrioritizedReplay(capacity=8, seed=0)
+    seq = {"obs": np.zeros((4, 2), np.float32)}
+    for _ in range(3):
+        buf.add(seq, priority=1.0)
+    for _ in range(2):
+        buf.sample(2)
+    assert (buf.adds, buf.samples) == (3, 2)
+    assert buf.sample_time_s > 0
+    before = buf.add_wait_s
+    held = threading.Event()
+
+    def hold():
+        with buf._lock:
+            held.set()
+            time.sleep(0.05)
+
+    t = threading.Thread(target=hold)
+    t.start()
+    held.wait()
+    buf.add(seq, priority=1.0)
+    t.join()
+    assert buf.adds == 4
+    assert buf.add_wait_s - before >= 0.04
+
+
+def test_replay_counts_every_add_under_concurrent_writers():
+    import sys
+
+    from repro.core.replay import PrioritizedReplay
+
+    buf = PrioritizedReplay(capacity=16, seed=0)
+    seq = {"obs": np.zeros(2, np.float32)}
+    threads = [threading.Thread(
+        target=lambda: [buf.add(seq, 1.0) for _ in range(200)])
+        for _ in range(8)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30.0)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert buf.adds == 8 * 200 and len(buf) == 16
+    assert buf.add_wait_s >= 0
+
+
+def test_replay_spans():
+    from repro.core.replay import PrioritizedReplay
+
+    tr = Tracer(enabled=True)
+    buf = PrioritizedReplay(capacity=4, seed=0, tracer=tr)
+    buf.add({"obs": np.zeros(3, np.float32)}, priority=1.0)
+    buf.sample(2)
+    spans = _spans(tr)
+    assert set(spans) == {"replay/add", "replay/sample", "replay/lock_wait",
+                          "replay/gather"}
+    add, sample = spans["replay/add"][0], spans["replay/sample"][0]
+    waits = spans["replay/lock_wait"]
+    assert _inside(waits[0], add) and _inside(waits[1], sample)
+    assert _inside(spans["replay/gather"][0], sample)
+
+
+def test_vtrace_batcher_spans():
+    from repro.onpolicy import TrajectoryQueue, VTraceBatcher
+
+    tr = Tracer(enabled=True)
+    q = TrajectoryQueue(8)
+    for _ in range(2):
+        q.put({"obs": np.zeros((3, 2), np.float32),
+               "actions": np.zeros(3, np.int32),
+               "rewards": np.zeros(3, np.float32),
+               "dones": np.zeros(3, np.float32),
+               "behavior_logprobs": np.zeros(3, np.float32)})
+    batch, _ = VTraceBatcher(q, batch_size=2, tracer=tr)()
+    assert batch["obs"].shape == (2, 3, 2)
+    assert set(_spans(tr)) == {"onpolicy/pop_batch", "onpolicy/assemble"}
+
+
+def test_sampling_policy_spans():
+    import jax.numpy as jnp
+
+    from repro.onpolicy import SamplingPolicy
+
+    def apply_fn(params, obs):
+        return obs @ params, jnp.zeros(obs.shape[:-1])
+
+    pol = SamplingPolicy(apply_fn, np.ones((50, 3), np.float32))
+    assert pol.tracer is None
+    # the system hands its tracer to the policy step it was given
+    tel = Telemetry(process_name="learner")
+    SeedSystem(env_factory=CatchEnv, policy_step=pol, num_actors=1,
+               unroll=4, envs_per_actor=2, algo="vtrace",
+               policy_publish=pol.publish, telemetry=tel)
+    assert pol.tracer is tel.tracer
+    out = pol(np.ones((4, 50), np.float32), None)
+    assert out.shape == (4, 2)
+    assert set(_spans(tel.tracer)) == {"policy/key", "policy/dispatch",
+                                       "policy/fetch"}
+
+
+def test_rollout_worker_counts_flush_time_and_traces_each_scan():
+    import jax
+
+    from repro.rollout import DeviceRolloutEngine, RolloutWorker
+
+    def policy_apply(params, core, obs, key):
+        return jax.random.randint(key, obs.shape[:1], 0, 3), core
+
+    tr = Tracer(enabled=True)
+    eng = DeviceRolloutEngine(CatchEnv, policy_apply, 2, 4, seed=0)
+    sunk = []
+    w = RolloutWorker(0, eng, sunk.append, lambda: (None, 0), tracer=tr)
+    w.start()
+    deadline = time.time() + 10.0
+    while w.iterations < 2 and time.time() < deadline:
+        time.sleep(0.01)
+    w.stop()
+    w.join()
+    assert w.error is None, w.error
+    assert w.flush_time_s > 0
+    spans = _spans(tr)
+    assert set(spans) == {"rollout/scan", "rollout/flush"}
+    assert len(spans["rollout/flush"]) >= 2
+
+
+def test_r2d2_system_traces_every_layer_it_runs():
+    import functools
+
+    from repro.configs.r2d2_atari import AtariConfig
+    from repro.core.r2d2_agent import build_r2d2_system
+    from repro.envs.alesim import ALESimEnv
+
+    acfg = AtariConfig(obs_size=36, obs_channels=1, core_dim=16,
+                       num_actions=4, burn_in=2, unroll=6, n_step=2)
+    tel = Telemetry(process_name="learner")
+    sys_ = build_r2d2_system(
+        acfg, functools.partial(ALESimEnv, frame=36, channels=1),
+        num_actors=2, envs_per_actor=2, learner_batch=2, replay_capacity=8,
+        min_replay=2, telemetry=tel)
+    stats = sys_.run(seconds=2.0)
+    assert stats["learner_error"] is None, stats["learner_error"]
+    assert stats["learner_steps"] >= 1
+    names = set(_spans(tel.tracer))
+    assert {"learner/step", "learner/input", "learner/train",
+            "learner/priority_update", "replay/add", "replay/sample",
+            "replay/gather", "inference/forward", "policy/slot_gather",
+            "policy/dispatch", "policy/fetch", "policy/slot_scatter",
+            "actor/env_step"} <= names
+    replay, ln = sys_.replay, sys_.learner
+    assert replay.samples >= ln.steps and replay.adds > 0
+    assert ln.post_time_s > 0
+    # the counters reach /varz (throughput) and the /metrics collector
+    timings = stats["timings"]
+    assert timings["replay_samples"] == replay.samples
+    assert timings["replay_adds"] == replay.adds
+    assert timings["learner_post_s"] == ln.post_time_s
+    assert timings["rollout_flush_s"] == 0.0
+    gauges = sys_._ops_ledger_gauges()
+    assert gauges["timings/replay_add_wait_s"] == replay.add_wait_s

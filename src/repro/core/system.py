@@ -503,6 +503,7 @@ class SeedSystem:
             "replay_samples": rp.samples,
             "replay_sample_s": rp.sample_time_s,
             "replay_adds": rp.adds,
+            "replay_add_s": rp.add_time_s,
             "replay_add_wait_s": rp.add_wait_s,
             "rollout_flush_s": sum(getattr(a, "flush_time_s", 0.0)
                                    for a in self.actors),

@@ -637,6 +637,56 @@ def test_replay_counts_every_add_under_concurrent_writers():
     assert buf.add_wait_s >= 0
 
 
+def test_replay_samples_whole_rows_under_concurrent_writers():
+    """Every row a sample returns is one add's: all its keys carry the
+    same id, while eight writers overwrite the ring."""
+    import sys
+
+    from repro.core.replay import PrioritizedReplay
+
+    buf = PrioritizedReplay(capacity=16, seed=0)
+
+    def seq(i):
+        return {"obs": np.full((4, 3), i, np.float32),
+                "actions": np.full((4,), i, np.int32),
+                "rewards": np.full((2,), i, np.float32)}
+
+    for i in range(16):
+        buf.add(seq(i), 1.0)
+    stop = threading.Event()
+    torn = []
+
+    def writer(w):
+        for j in range(200):
+            buf.add(seq(1000 * (w + 1) + j), 1.0)
+
+    def sampler():
+        while not stop.is_set():
+            batch, _, _ = buf.sample(8)
+            rows = np.concatenate([np.asarray(v).reshape(8, -1)
+                                   for v in batch.values()], axis=1)
+            torn.extend(r for r in rows if (r != r[0]).any())
+
+    threads = [threading.Thread(target=writer, args=(w,)) for w in range(8)]
+    reader = threading.Thread(target=sampler)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        reader.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+    finally:
+        stop.set()
+        reader.join(timeout=60.0)
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads + [reader])
+    assert buf.adds == 16 + 8 * 200 and buf.samples > 0
+    assert not torn, torn[:3]
+    assert buf.add_time_s >= buf.add_wait_s
+
+
 def test_replay_spans():
     from repro.core.replay import PrioritizedReplay
 
@@ -748,5 +798,7 @@ def test_r2d2_system_traces_every_layer_it_runs():
     assert timings["replay_adds"] == replay.adds
     assert timings["learner_post_s"] == ln.post_time_s
     assert timings["rollout_flush_s"] == 0.0
+    assert timings["replay_add_s"] == replay.add_time_s >= replay.add_wait_s
     gauges = sys_._ops_ledger_gauges()
     assert gauges["timings/replay_add_wait_s"] == replay.add_wait_s
+    assert gauges["timings/replay_add_s"] == replay.add_time_s

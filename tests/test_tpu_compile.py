@@ -11,6 +11,7 @@ only one process may load the TPU library, and every test worker imports
 this file.
 """
 
+import math
 import os
 
 import jax
@@ -131,3 +132,25 @@ def test_r2d2_policy_step_compiles_for_v5e_at_256_lanes(one_chip):
 
     _compile(policy, _shapes(params, one_chip), _shapes(obs, one_chip),
              *_shapes(core, one_chip))
+
+
+def test_r2d2_replay_gather_compiles_for_v5e(one_chip):
+    """The replay's gather at the benchmark cell's batch (64 rows of 120
+    steps): rows in the layout the chip gives a row become one batch,
+    with no temporary the size of a batch."""
+    from repro.core.replay import _stack_rows
+
+    acfg = AtariConfig()
+    t, batch = acfg.burn_in + acfg.unroll, 64
+    row = {"obs": ((t, acfg.obs_size, acfg.obs_size, acfg.obs_channels),
+                   jnp.uint8),
+           "actions": ((t,), jnp.int32), "rewards": ((t,), jnp.float32),
+           "dones": ((t,), jnp.float32)}
+    rows = {k: [jax.ShapeDtypeStruct(s, d, sharding=one_chip)] * batch
+            for k, (s, d) in row.items()}
+    mem = _stack_rows.lower(rows).compile().memory_analysis()
+    one_batch = batch * sum(
+        jnp.dtype(d).itemsize * math.prod(s) for s, d in row.values())
+    # the output is one batch, laid out in the chip's tiles
+    assert one_batch <= mem.output_size_in_bytes < 1.25 * one_batch, mem
+    assert mem.temp_size_in_bytes < one_batch // 8, mem

@@ -118,8 +118,8 @@ def measured_backend_sweep(num_actors=2, envs_per_actor=8, seconds=1.0,
     def host_policy(obs, ids):
         return np.random.randint(0, CatchEnv.num_actions, size=(obs.shape[0],))
 
-    def device_policy(params, core, obs, key):
-        return jax.random.randint(key, (obs.shape[0],), 0,
+    def device_policy(params, core, inputs, key):
+        return jax.random.randint(key, (inputs.obs.shape[0],), 0,
                                   CatchEnv.num_actions), core
 
     points = (("per_step_host", "host", 1),
@@ -192,8 +192,8 @@ def measured_engine_shard_sweep(shard_counts=(1, 2), num_actors=2,
     host the same code overlaps them."""
     import jax
 
-    def device_policy(params, core, obs, key):
-        return jax.random.randint(key, (obs.shape[0],), 0,
+    def device_policy(params, core, inputs, key):
+        return jax.random.randint(key, (inputs.obs.shape[0],), 0,
                                   CatchEnv.num_actions), core
 
     rows = []

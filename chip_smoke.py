@@ -1,7 +1,7 @@
 """Bring-up check on the TPU: drive the SEED stack's main paths once through
 the `SeedSystem` entry points and check what comes out.
 
-    python chip_smoke.py              # one chip, three phases (below)
+    python chip_smoke.py              # one chip, four phases (below)
     python chip_smoke.py --chips 4    # the sharded rollout engine, 4 chips
 
 One chip runs, in this process:
@@ -11,6 +11,11 @@ One chip runs, in this process:
     central inference, prioritized replay and the learner;
   * vtrace_device — V-trace on the device backend: fused env+policy scans
     over pure-JAX Catch lanes feeding the on-policy queue;
+  * impala_deep_device — IMPALA's deep ResNet-LSTM at its published
+    `ImpalaConfig()` widths (84x84x4 frames, stacks of 16/32/32 channels,
+    LSTM 256, 18 actions) under V-trace on the device backend: scans over
+    `ALESimJaxEnv` lanes that record each unroll's starting core, and a
+    learner that unrolls the network from it;
   * vtrace_shm_hosts — V-trace with the actors in spawned host processes
     over shared-memory rings, 2 gateways and 2 inference replicas. The
     hosts step a pure-JAX env, so they run JAX too: on the CPU, while this
@@ -39,12 +44,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.configs.impala_atari import ImpalaConfig  # noqa: E402
 from repro.configs.r2d2_atari import AtariConfig  # noqa: E402
 from repro.core.r2d2_agent import build_r2d2_system  # noqa: E402
 from repro.core.system import SeedSystem  # noqa: E402
-from repro.envs.alesim import ALESimEnv  # noqa: E402
+from repro.envs.alesim import ALESimEnv, ALESimJaxEnv  # noqa: E402
 from repro.envs.catch import CatchEnv  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.models.impala import impala_actor_critic  # noqa: E402
 from repro.onpolicy import VTraceLearner, mlp_actor_critic  # noqa: E402
 from repro.optim import adamw  # noqa: E402
 from repro.rollout import ShardedRolloutEngine  # noqa: E402
@@ -171,6 +178,45 @@ def phase_vtrace_device(*, num_workers: int = 2, lanes: int = 256,
     return stats
 
 
+def phase_impala_deep_device(cfg: ImpalaConfig = ImpalaConfig(), *,
+                             num_workers: int = 2, lanes: int = 64,
+                             unroll: int = 20, learner_batch: int = 32,
+                             step_cost: int = 4096,
+                             seconds: float = 10.0) -> dict:
+    phase = "impala_deep_device"
+    t0 = time.perf_counter()
+    init_fn, apply_fn, init_core = impala_actor_critic(cfg)
+    vl = VTraceLearner(apply_fn, adamw(6e-4, max_grad_norm=40.0),
+                       init_core=init_core)
+    state = vl.init_state(init_fn(jax.random.PRNGKey(0)))
+    frame = (cfg.obs_size, cfg.obs_size, cfg.obs_channels)
+    vl.warmup(state, batch_size=learner_batch, unroll=unroll,
+              obs_shape=frame, obs_dtype=np.uint8)
+    env = functools.partial(ALESimJaxEnv, frame=cfg.obs_size,
+                            channels=cfg.obs_channels, step_cost=step_cost)
+    sys_ = SeedSystem(env_factory=env, backend="device",
+                      policy_apply=vl.device_policy_apply(),
+                      init_core=init_core, num_actors=num_workers,
+                      unroll=unroll, envs_per_actor=lanes, algo="vtrace",
+                      train_step=vl.train_step, state=state,
+                      learner_batch=learner_batch,
+                      queue_capacity=4 * learner_batch)
+    sys_.warmup()
+    setup_s = time.perf_counter() - t0
+    stats = sys_.run(seconds=seconds)
+    _check_errors(phase, stats)
+    led = _check_ledger(phase, stats)
+    _say(phase, setup_s=setup_s, env_frames=stats["env_frames"],
+         learner_steps=stats["learner_steps"], **led,
+         rollout_scan_s=stats["timings"]["rollout_scan_s"],
+         params_platform=_platform_of(sys_.learner.state["params"]),
+         scan_platform=_platform_of(sys_.actors[0].engine._carry))
+    _require_learner_steps(phase, stats)
+    _require_placed(phase, params=sys_.learner.state["params"],
+                    scans=sys_.actors[0].engine._carry)
+    return stats
+
+
 def phase_vtrace_shm_hosts(*, num_hosts: int = 2, num_gateways: int = 2,
                            num_replicas: int = 2, actors_per_host: int = 2,
                            envs_per_actor: int = 8, unroll: int = 20,
@@ -274,7 +320,7 @@ def phase_sharded_engine(devices, *, lanes: int = 256, unroll: int = 20,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="1: the three one-chip phases; 4: only the "
+                    help="1: the four one-chip phases; 4: only the "
                          "sharded rollout engine across four chips")
     args = ap.parse_args(argv)
     t0 = time.perf_counter()
@@ -296,6 +342,7 @@ def main(argv=None) -> int:
     else:
         phase_r2d2_full_width()
         phase_vtrace_device()
+        phase_impala_deep_device()
         phase_vtrace_shm_hosts()
     print(f"chip_smoke: total_s={time.perf_counter() - t0}", flush=True)
     print(json.dumps({"ok": True, "device": {

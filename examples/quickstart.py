@@ -56,8 +56,8 @@ def vector_actor_demo(env_counts=(1, 8), seconds=0.6):
         print(f"  E={E}: {stats['env_frames_per_s']:8.0f} env-frames/s "
               f"({stats['actor_iterations']} iterations x {E} lanes)")
 
-    def policy_apply(params, core, obs, key):
-        return jax.random.randint(key, (obs.shape[0],), 0, 3), core
+    def policy_apply(params, core, inputs, key):
+        return jax.random.randint(key, (inputs.obs.shape[0],), 0, 3), core
 
     E = env_counts[-1]
     sys_ = SeedSystem(env_factory=CatchEnv, backend="device",
@@ -115,8 +115,8 @@ def sharded_inference_demo(E=8, seconds=0.8):
 
     # the device path shards the other way: engine_shards=K places K fused
     # scan engines round-robin over jax.devices() (one carry per device)
-    def policy_apply(params, core, obs, key):
-        return jax.random.randint(key, (obs.shape[0],), 0, 3), core
+    def policy_apply(params, core, inputs, key):
+        return jax.random.randint(key, (inputs.obs.shape[0],), 0, 3), core
 
     sys_ = SeedSystem(env_factory=CatchEnv, backend="device",
                       policy_apply=policy_apply, num_actors=2, unroll=8,

@@ -15,6 +15,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.core.inference import ReplyError
@@ -33,13 +34,21 @@ def flush_lane_unrolls(stacked, sink: Callable, extra=None):
     `RolloutWorker`s, and wire TRAJ frames) feed the trajectory sink.
     Any key in `stacked` is split along the lane axis (on-policy rollouts
     add ``behavior_logprobs``); ``extra`` entries (e.g. the behavior
-    ``param_version`` stamp) are copied verbatim into every lane record."""
+    ``param_version`` stamp) are copied verbatim into every lane record.
+    A recurrent device policy's ``start`` (lane-major: each lane's core
+    and step-0 inputs) is split along its first axis, and its fields
+    become the record's own keys."""
+    start = stacked.get("start")
     for lane in range(stacked["actions"].shape[1]):
         rec = {}
         for k, v in stacked.items():
+            if k == "start":
+                continue
             lane_v = v[:, lane]
             dtype = _LANE_DTYPES.get(k)
             rec[k] = lane_v if dtype is None else lane_v.astype(dtype)
+        if start is not None:
+            rec.update(jax.tree.map(lambda x: x[lane], start))
         if extra:
             rec.update(extra)
         sink(rec)

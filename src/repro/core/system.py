@@ -13,8 +13,10 @@ Backends (see `repro.rollout` for the design-point taxonomy):
     is a host callable `(obs, slot_ids) -> actions`);
   * `backend="device"`: `RolloutWorker` threads drive fused env+policy
     `lax.scan` unrolls on the accelerator (`policy_apply` is a pure
-    function `(params, core, obs, key) -> (actions, core)`); params refresh
-    from the learner between scans via the publish/version seam.
+    function `(params, core, inputs, key) -> (actions, core)`, `inputs` a
+    `rollout.StepInputs`); params refresh from the learner between scans
+    via the publish/version seam. A recurrent policy (``init_core``)
+    trains with V-trace only here.
 
 Algorithms (`algo=`): the trajectory plane the actors feed is selected
 independently of the rollout backend:
@@ -132,6 +134,12 @@ class SeedSystem:
                     raise ValueError(
                         f"{name}={val} applies to algo='vtrace' (replay-"
                         f"based R2D2 has no trajectory queue to tune)")
+        if algo == "vtrace" and backend == "host" and init_core is not None:
+            raise ValueError(
+                "init_core with algo='vtrace' needs backend='device': the "
+                "host path keeps no per-slot V-trace core, and its "
+                "inference requests carry no reward or done to feed or "
+                "reset one")
         queue_capacity = 64 if queue_capacity is None else queue_capacity
         gamma = 0.99 if gamma is None else gamma
         if transport not in ("inproc", "socket", "shm"):
@@ -505,6 +513,10 @@ class SeedSystem:
             "replay_adds": rp.adds,
             "replay_add_s": rp.add_time_s,
             "replay_add_wait_s": rp.add_wait_s,
+            "rollout_scan_s": sum(getattr(a, "scan_time_s", 0.0)
+                                  for a in self.actors),
+            "rollout_fetch_bytes": sum(getattr(a, "fetch_bytes", 0)
+                                       for a in self.actors),
             "rollout_flush_s": sum(getattr(a, "flush_time_s", 0.0)
                                    for a in self.actors),
         }

@@ -5,9 +5,14 @@ end-to-end RL training. ALE itself is not available offline, so this host
 (numpy) environment emulates an Atari game loop: it produces 84x84x4
 frames and burns a calibratable amount of CPU per step, so the actor-count
 sweep measures real contention on real hardware threads — the quantity the
-paper studies — rather than game logic.
+paper studies — rather than game logic. `ALESimJaxEnv` is the same env
+in `jax.numpy`, for the device backend's fused scans.
 """
 
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -115,3 +120,57 @@ class ALESimEnv:
         if done:
             obs = self.reset()
         return obs, reward, done
+
+
+class ALESimState(NamedTuple):
+    frame: jax.Array      # (frame, frame) float32: what _render draws from
+    work: jax.Array       # (step_cost,) float32: the per-step workload
+    t: jax.Array          # int32 steps into the episode
+    key: jax.Array        # draws the next episode's frame
+
+
+class ALESimJaxEnv:
+    """`ALESimEnv`'s dynamics in `jax.numpy`: pure and vmappable, so the
+    device backend's fused scan can step it. The same state update, the
+    same `step_cost` dot product, the same 84x84xC uint8 frames (a value
+    past 255 wraps as numpy's cast does), the same pseudo-reward, and an
+    auto-reset after ``episode_len`` steps that draws the next episode's
+    frame from the lane's key. The workload vector is drawn once per lane
+    at `reset` and kept across episodes, as `ALESimEnv` keeps ``_work``."""
+
+    num_actions = ALESimEnv.num_actions
+
+    def __init__(self, frame=84, channels=4, step_cost=4096,
+                 episode_len=1000):
+        self.frame, self.channels = frame, channels
+        self.step_cost = step_cost
+        self.episode_len = episode_len
+        self.obs_shape = (frame, frame, channels)
+
+    def reset(self, key):
+        key, k_work, k_frame = jax.random.split(key, 3)
+        st = ALESimState(
+            frame=jax.random.uniform(k_frame, (self.frame, self.frame)),
+            work=jax.random.uniform(k_work, (self.step_cost,)),
+            t=jnp.zeros((), jnp.int32), key=key)
+        return st, self.render(st.frame)
+
+    def render(self, frame):
+        f = (frame * 255).astype(jnp.int32).astype(jnp.uint8)
+        return jnp.stack([jnp.roll(f, i, axis=0)
+                          for i in range(self.channels)], axis=-1)
+
+    def step(self, st, action):
+        w = st.work
+        acc = jnp.dot(w, jnp.roll(w, action + 1))
+        frame = jnp.abs(jnp.roll(st.frame, 1, axis=1) * 0.999 + 1e-4 * acc)
+        frame = frame.at[0, 0].set(acc % 1.0)
+        t = st.t + 1
+        done = t >= self.episode_len
+        reward = (frame[0, 0] > 0.5).astype(jnp.float32)
+        key, k_frame = jax.random.split(st.key)
+        frame = jnp.where(done, jax.random.uniform(k_frame, frame.shape),
+                          frame)
+        st = ALESimState(frame=frame, work=w,
+                         t=jnp.where(done, 0, t), key=key)
+        return st, self.render(frame), reward, done

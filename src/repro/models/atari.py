@@ -22,7 +22,7 @@ def _conv_out_hw(hw, kernel, stride):
     return (hw - kernel) // stride + 1
 
 
-def _init_conv(mk, name, cin, cout, k):
+def init_conv(mk, name, cin, cout, k):
     return {
         "w": mk(f"{name}.w", (k, k, cin, cout), (None, None, None, None),
                 inits.fan_in(in_axes=(0, 1, 2))),
@@ -50,7 +50,7 @@ def _build(cfg, mk):
     p = {}
     cin = cfg.obs_channels
     for i, (feats, k, s) in enumerate(CONVS):
-        p[f"conv{i}"] = _init_conv(mk, f"conv{i}", cin, feats, k)
+        p[f"conv{i}"] = init_conv(mk, f"conv{i}", cin, feats, k)
         cin = feats
     flat = _torso_dims(cfg)
     p["torso_out"] = {

@@ -8,10 +8,18 @@ policy) and ``param_version`` (stamped per unroll by the generator). The
 batcher stacks B of them into the exact field set `core.vtrace` consumes:
 obs, actions, rewards, discounts (= gamma * (1 - done), 0 at terminals),
 and behavior_logprobs, all (B, T) with time as the second axis.
+
+Unrolls of a recurrent device policy also carry the core each lane held
+before step 0 and that step's inputs (``core``, ``prev_action``,
+``prev_reward``, ``first``; see `rollout.engine`). Their batch gains
+``core`` (B, ...) and every step's ``prev_action``, ``prev_reward`` and
+``first`` (B, T): step 0's as recorded, each later step's from the step
+before it. A stateless policy's batch has none of these keys.
 """
 
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.learner import BatchSourceClosed
@@ -44,7 +52,32 @@ def assemble_vtrace_batch(unrolls: List[Dict[str, np.ndarray]],
     batch["param_version"] = np.asarray(
         [int(np.asarray(u.get("param_version", 0)).reshape(()))
          for u in unrolls], np.int64)
+    if "core" in unrolls[0]:
+        batch.update(_recurrent_fields(unrolls, batch, dones))
     return batch
+
+
+def _recurrent_fields(unrolls, batch, dones):
+    """The core before step 0 and each step's previous action and reward
+    and episode-start flag: recorded for step 0, else the step before's
+    (0 where that step ended its episode)."""
+    def step0(key, dtype):
+        return np.asarray([u[key] for u in unrolls], dtype)[:, None]
+
+    ended = dones[:, :-1] > 0
+    return {
+        "core": jax.tree.map(lambda *xs: np.stack(xs),
+                             *[u["core"] for u in unrolls]),
+        "prev_action": np.concatenate(
+            [step0("prev_action", np.int32),
+             np.where(ended, 0, batch["actions"][:, :-1])], 1
+        ).astype(np.int32),
+        "prev_reward": np.concatenate(
+            [step0("prev_reward", np.float32),
+             np.where(ended, 0.0, batch["rewards"][:, :-1])], 1
+        ).astype(np.float32),
+        "first": np.concatenate([step0("first", bool), ended], 1),
+    }
 
 
 class VTraceBatcher:

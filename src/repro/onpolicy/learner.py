@@ -20,10 +20,18 @@ sampled action (V-trace's denominator). Two adapters cover the backends:
   * `make_device_sampling_policy` — the device-backend counterpart: a
     pure ``policy_apply`` returning (actions, logprobs, core) for the
     fused scan (`DeviceRolloutEngine(with_logprobs=True)`).
+
+Every policy is ``apply_fn(params, core, inputs) -> (logits, values,
+core)`` over (B, T) inputs. A stateless one (`mlp_actor_critic`) reads
+``inputs["obs"]`` alone and carries a ``None`` core; a recurrent one
+(`models.impala`) also reads ``prev_action``, ``prev_reward`` and
+``first`` and unrolls from the core before step 0. A recurrent policy
+trains only on the device backend, whose scans record each unroll's
+starting core.
 """
 
 import threading
-from typing import Callable, Tuple  # noqa: F401 (Tuple in annotations)
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,9 +44,10 @@ from repro.telemetry.tracer import maybe_span
 
 def mlp_actor_critic(obs_dim: int, num_actions: int, hidden: int = 64):
     """Tiny shared-torso actor-critic: returns (init_fn, apply_fn) with
-    ``apply_fn(params, obs[..., obs_dim]) -> (logits[..., A], value[...])``
-    — rank-polymorphic, so the same function serves (N,) inference
-    batches and (B, T) learner batches."""
+    ``apply_fn(params, core, {"obs": obs[..., obs_dim]}) -> (logits[...,
+    A], value[...], core)``, stateless (the core passes through) and
+    rank-polymorphic, so the same function serves (N,) inference batches
+    and (B, T) learner batches."""
 
     def init_fn(key):
         k1, k2, k3 = jax.random.split(key, 3)
@@ -52,11 +61,11 @@ def mlp_actor_critic(obs_dim: int, num_actions: int, hidden: int = 64):
             "bv": jnp.zeros((1,)),
         }
 
-    def apply_fn(params, obs):
-        h = jax.nn.relu(obs @ params["w1"] + params["b1"])
+    def apply_fn(params, core, inputs):
+        h = jax.nn.relu(inputs["obs"] @ params["w1"] + params["b1"])
         logits = h @ params["wp"] + params["bp"]
         value = (h @ params["wv"] + params["bv"])[..., 0]
-        return logits, value
+        return logits, value, core
 
     return init_fn, apply_fn
 
@@ -67,14 +76,17 @@ def make_vtrace_train_step(apply_fn: Callable, optimizer, *,
                            entropy_coef: float = 0.01):
     """train_step(state, batch) -> (state, metrics) over V-trace batches.
 
-    ``apply_fn(params, obs[B, T, ...]) -> (logits[B, T, A], values[B, T])``;
-    batch fields are the `assemble_vtrace_batch` schema. The state dict is
-    the standard {params, opt_state, step} pytree, so checkpointing and
-    the `Learner` publish seam work unchanged.
+    ``apply_fn(params, core, batch) -> (logits[B, T, A], values[B, T],
+    core)`` reads its inputs from the batch and starts from
+    ``batch["core"]``, which only a recurrent policy's batch carries (the
+    core recorded before step 0; ``first`` resets it). Batch fields are
+    the `assemble_vtrace_batch` schema. The state dict is the standard
+    {params, opt_state, step} pytree, so checkpointing and the `Learner`
+    publish seam work unchanged.
     """
 
     def loss_fn(params, batch):
-        logits, values = apply_fn(params, batch["obs"])
+        logits, values, _ = apply_fn(params, batch.get("core"), batch)
         logp = jax.nn.log_softmax(logits)
         taken = jnp.take_along_axis(
             logp, batch["actions"][..., None], axis=-1)[..., 0]
@@ -108,20 +120,24 @@ def make_vtrace_train_step(apply_fn: Callable, optimizer, *,
 
 
 class VTraceLearner:
-    """The on-policy learner bundle for one (logits, value) policy: the
+    """The on-policy learner bundle for one actor-critic policy: the
     jitted V-trace `train_step` (what `SeedSystem(algo="vtrace")` drives
     through the generic `Learner` loop), fresh train state, the two
     sampling adapters, and a warmup that pre-compiles the step at the
     system's batch shape. `assemble_vtrace_batch` keeps the batch pytree
     structure fixed, so ONE warmup covers the whole run — without it the
     first real batch compiles inside the measured window (observed 3.2 s
-    vs the 80 ms steady step on a 2-core host)."""
+    vs the 80 ms steady step on a 2-core host). A recurrent policy also
+    gives ``init_core(n)``, the zero core of n lanes: the warmup batch
+    carries it, and the host-backend sampling policy is refused."""
 
     def __init__(self, apply_fn: Callable, optimizer, *,
+                 init_core: Optional[Callable] = None,
                  rho_bar: float = 1.0, c_bar: float = 1.0,
                  value_coef: float = 0.5, entropy_coef: float = 0.01):
         self.apply_fn = apply_fn
         self.optimizer = optimizer
+        self.init_core = init_core
         self.train_step = jax.jit(make_vtrace_train_step(
             apply_fn, optimizer, rho_bar=rho_bar, c_bar=c_bar,
             value_coef=value_coef, entropy_coef=entropy_coef))
@@ -136,17 +152,28 @@ class VTraceLearner:
         """Compile the train step on a structurally-identical dummy batch
         (state is NOT advanced)."""
         from repro.onpolicy.batcher import assemble_vtrace_batch
-        dummy = [{"obs": np.zeros((unroll,) + tuple(obs_shape), obs_dtype),
-                  "actions": np.zeros((unroll,), np.int32),
-                  "rewards": np.zeros((unroll,), np.float32),
-                  "dones": np.zeros((unroll,), np.float32),
-                  "behavior_logprobs": np.zeros((unroll,), np.float32)}
-                 ] * batch_size
-        self.train_step(state, assemble_vtrace_batch(dummy, gamma=0.99))
+        dummy = {"obs": np.zeros((unroll,) + tuple(obs_shape), obs_dtype),
+                 "actions": np.zeros((unroll,), np.int32),
+                 "rewards": np.zeros((unroll,), np.float32),
+                 "dones": np.zeros((unroll,), np.float32),
+                 "behavior_logprobs": np.zeros((unroll,), np.float32)}
+        if self.init_core is not None:
+            dummy.update(core=jax.tree.map(lambda x: np.asarray(x)[0],
+                                           self.init_core(1)),
+                         prev_action=np.int32(0), prev_reward=np.float32(0),
+                         first=True)
+        self.train_step(state, assemble_vtrace_batch([dummy] * batch_size,
+                                                     gamma=0.99))
 
     def sampling_policy(self, params, seed: int = 0) -> "SamplingPolicy":
         """Host-backend `policy_step` (wire `.publish` via
-        `SeedSystem(policy_publish=...)`)."""
+        `SeedSystem(policy_publish=...)`); stateless policies only."""
+        if self.init_core is not None:
+            raise ValueError(
+                "a recurrent V-trace policy has no host-backend sampling "
+                "policy: central inference keeps no per-slot V-trace core "
+                "and its requests carry no reward or done; use "
+                "backend='device'")
         return SamplingPolicy(self.apply_fn, params, seed=seed)
 
     def device_policy_apply(self) -> Callable:
@@ -154,13 +181,17 @@ class VTraceLearner:
         return make_device_sampling_policy(self.apply_fn)
 
 
+def _sample(logits, key):
+    actions = jax.random.categorical(key, logits)
+    lp = jnp.take_along_axis(jax.nn.log_softmax(logits),
+                             actions[..., None], axis=-1)[..., 0]
+    return actions, lp
+
+
 def _sample_with_logprobs(apply_fn):
     def fn(params, obs, key):
-        logits, _ = apply_fn(params, obs)
-        actions = jax.random.categorical(key, logits)
-        lp = jnp.take_along_axis(jax.nn.log_softmax(logits),
-                                 actions[..., None], axis=-1)[..., 0]
-        return actions, lp
+        logits, _, _ = apply_fn(params, None, {"obs": obs})
+        return _sample(logits, key)
     return fn
 
 
@@ -209,13 +240,16 @@ class SamplingPolicy:
 
 def make_device_sampling_policy(apply_fn: Callable):
     """Device-backend counterpart of `SamplingPolicy`: a pure
-    ``policy_apply(params, core, obs, key) -> (actions, logprobs, core)``
-    for `DeviceRolloutEngine(with_logprobs=True)` — the logprob rides the
-    fused scan and comes back inside the trajectory pytree."""
-    sample = _sample_with_logprobs(apply_fn)
+    ``policy_apply(params, core, inputs, key) -> (actions, logprobs,
+    core)`` for `DeviceRolloutEngine(with_logprobs=True)` — the logprob
+    rides the fused scan and comes back inside the trajectory pytree. It
+    is the learner's apply at T = 1; an engine built without
+    ``init_core`` hands it a ``None`` core."""
 
-    def policy_apply(params, core, obs, key):
-        actions, lp = sample(params, obs, key)
+    def policy_apply(params, core, inputs, key):
+        step = {k: v[:, None] for k, v in inputs._asdict().items()}
+        logits, _, core = apply_fn(params, core, step)
+        actions, lp = _sample(logits[:, 0], key)
         return actions, lp, core
 
     return policy_apply

@@ -30,6 +30,6 @@ replay sink as the host actors.
 """
 
 from repro.rollout.engine import (DeviceRolloutEngine,  # noqa: F401
-                                  ShardedRolloutEngine, action_key,
-                                  as_jax_env)
+                                  ShardedRolloutEngine, StepInputs,
+                                  action_key, as_jax_env)
 from repro.rollout.worker import RolloutWorker  # noqa: F401

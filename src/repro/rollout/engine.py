@@ -15,15 +15,51 @@ Determinism contract (what the parity tests pin down):
   * the per-step action key stream is `fold_in(PRNGKey(seed), 1)` split
     once per scan step (see `action_key`), so stochastic policies are
     reproducible against a host reference following the same stream.
+
+Every device policy has one signature, ``policy_apply(params, core,
+inputs, key)``: ``inputs`` is a `StepInputs` of each lane's observation,
+previous action and reward, and episode-start flag. A recurrent policy
+(one built with ``init_core``) zeroes its core where ``first`` is set,
+and each trajectory then also records, under ``start``, the core every
+lane held before the unroll's first step and that step's inputs, which
+is what a learner needs to replay the unroll.
 """
 
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.envs.vector import _is_jax_env, as_env_instance
+from repro.telemetry.tracer import maybe_span
+
+
+class StepInputs(NamedTuple):
+    """What a device policy sees of each lane at one step. At an episode's
+    first step (``first`` set) there is no previous step: ``prev_action``
+    and ``prev_reward`` are 0."""
+    obs: jax.Array
+    prev_action: jax.Array     # (E,) int32
+    prev_reward: jax.Array     # (E,) float32
+    first: jax.Array           # (E,) bool
+
+
+def first_inputs(obs) -> StepInputs:
+    """The inputs of every lane's first step after a reset."""
+    n = obs.shape[0]
+    return StepInputs(obs, jnp.zeros((n,), jnp.int32),
+                      jnp.zeros((n,), jnp.float32), jnp.ones((n,), bool))
+
+
+def next_inputs(nobs, actions, rewards, dones) -> StepInputs:
+    """The inputs of the step after one that took ``actions`` and got
+    ``rewards`` and ``dones``; an env that auto-resets hands back the next
+    episode's first observation where ``dones`` is set."""
+    dones = dones.astype(bool)
+    return StepInputs(nobs, jnp.where(dones, 0, actions).astype(jnp.int32),
+                      jnp.where(dones, 0.0, rewards).astype(jnp.float32),
+                      dones)
 
 
 def as_jax_env(env):
@@ -49,11 +85,13 @@ def action_key(seed: int) -> jax.Array:
 class DeviceRolloutEngine:
     """Fused env+policy unrolls for one batch of E lanes.
 
-    policy_apply: (params, core, obs[E, ...], key) -> (actions[E], core) —
-    a pure function; `core` is any pytree of per-lane recurrent state (or
-    None for feed-forward policies). One `rollout(params)` call advances
-    all lanes T steps on-device and returns the host-side trajectory dict
-    {obs (T,E,...), actions (T,E) i32, rewards (T,E) f32, dones (T,E) bool}.
+    policy_apply: (params, core, inputs: StepInputs, key) -> (actions[E],
+    core) — a pure function; `core` is any pytree of per-lane recurrent
+    state (or None for feed-forward policies). One `rollout(params)` call
+    advances all lanes T steps on-device and returns the host-side
+    trajectory dict {obs (T,E,...), actions (T,E) i32, rewards (T,E) f32,
+    dones (T,E) bool}; with ``init_core`` also ``start``: {core (E, ...),
+    prev_action, prev_reward, first (E,)} as they stood before step 0.
     """
 
     def __init__(self, env, policy_apply: Callable, num_envs: int,
@@ -86,23 +124,31 @@ class DeviceRolloutEngine:
 
         def unroll_fn(params, carry):
             def one_step(c, _):
-                env_state, core, obs, key = c
+                env_state, core, inputs, key = c
                 key, sub = jax.random.split(key)
                 if self.with_logprobs:
-                    actions, logprobs, core = policy_apply(params, core, obs,
-                                                           sub)
+                    actions, logprobs, core = policy_apply(params, core,
+                                                           inputs, sub)
                 else:
-                    actions, core = policy_apply(params, core, obs, sub)
+                    actions, core = policy_apply(params, core, inputs, sub)
                 actions = actions.astype(jnp.int32)
                 env_state, nobs, rewards, dones = vstep(env_state, actions)
-                out = {"obs": obs, "actions": actions,
+                out = {"obs": inputs.obs, "actions": actions,
                        "rewards": rewards.astype(jnp.float32),
                        "dones": dones}
                 if self.with_logprobs:
                     out["behavior_logprobs"] = logprobs.astype(jnp.float32)
-                return (env_state, core, nobs, key), out
+                return (env_state, core, next_inputs(nobs, actions, rewards,
+                                                     dones), key), out
 
-            return jax.lax.scan(one_step, carry, None, length=T)
+            _, core0, inputs0, _ = carry
+            carry, traj = jax.lax.scan(one_step, carry, None, length=T)
+            if self._init_core is not None:
+                traj["start"] = {"core": core0,
+                                 "prev_action": inputs0.prev_action,
+                                 "prev_reward": inputs0.prev_reward,
+                                 "first": inputs0.first}
+            return carry, traj
 
         return unroll_fn
 
@@ -117,7 +163,7 @@ class DeviceRolloutEngine:
         env_state, obs = self._reset(keys)
         core = self._init_core(self.num_envs) if self._init_core else None
         self._carry = self._place(
-            (env_state, core, obs, action_key(self._seed)))
+            (env_state, core, first_inputs(obs), action_key(self._seed)))
         return np.asarray(obs)
 
     def warmup(self, params):
@@ -140,11 +186,15 @@ class DeviceRolloutEngine:
         self.frames += self.unroll * self.num_envs
         return traj
 
-    def rollout(self, params) -> dict:
-        """Advance all lanes T steps in one device call; ONE host transfer."""
-        traj = self.dispatch(params)
-        host = jax.device_get(traj)       # the single per-unroll transfer
-        return {k: np.asarray(v) for k, v in host.items()}
+    def rollout(self, params, tracer=None) -> dict:
+        """Advance all lanes T steps in one device call; ONE host transfer.
+        With a ``tracer``, the launch is a ``rollout/dispatch`` span and
+        the wait for the trajectory on the host a ``rollout/fetch``."""
+        with maybe_span(tracer, "rollout/dispatch"):
+            traj = self.dispatch(params)
+        with maybe_span(tracer, "rollout/fetch"):
+            # the single per-unroll transfer
+            return jax.tree.map(np.asarray, jax.device_get(traj))
 
 
 class ShardedRolloutEngine:
@@ -214,12 +264,21 @@ class ShardedRolloutEngine:
         for e in self.engines:
             e.warmup(params)
 
-    def rollout(self, params) -> dict:
+    def rollout(self, params, tracer=None) -> dict:
         """Advance all lanes T steps: K device calls dispatched before any
         host transfer, then ONE gather per shard, concatenated on the lane
-        axis into the (T, E_total, ...) unroll schema."""
-        trajs = [e.dispatch(params) for e in self.engines]
-        hosts = [jax.device_get(t) for t in trajs]
-        self.scans += 1
-        return {k: np.concatenate([np.asarray(h[k]) for h in hosts], axis=1)
-                for k in hosts[0]}
+        axis into the (T, E_total, ...) unroll schema (``start``'s fields
+        are lane-major). Spans as `DeviceRolloutEngine.rollout`."""
+        with maybe_span(tracer, "rollout/dispatch"):
+            trajs = [e.dispatch(params) for e in self.engines]
+        with maybe_span(tracer, "rollout/fetch"):
+            hosts = [jax.device_get(t) for t in trajs]
+            self.scans += 1
+            out = {k: np.concatenate([np.asarray(h[k]) for h in hosts],
+                                     axis=1)
+                   for k in hosts[0] if k != "start"}
+            if "start" in hosts[0]:
+                out["start"] = jax.tree.map(
+                    lambda *xs: np.concatenate(xs, axis=0),
+                    *[h["start"] for h in hosts])
+            return out

@@ -14,6 +14,7 @@ import time
 import traceback
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.core.actor import account_episode_ends, flush_lane_unrolls
@@ -30,8 +31,12 @@ class RolloutWorker:
         every flushed lane record — the on-policy queue's admission key
         (replay records stay byte-identical without it). With a
         ``tracer``, each scan is a ``rollout/scan`` span (dispatch to the
-        trajectory on the host) and a ``rollout/flush`` span (episode
-        accounting and the unrolls handed to the sink)."""
+        trajectory on the host; inside it the engine's ``rollout/dispatch``
+        and ``rollout/fetch``) and a ``rollout/flush`` span (episode
+        accounting and the unrolls handed to the sink). Always counted:
+        ``scan_time_s``, the host time from dispatch until the trajectory
+        is on the host, and ``fetch_bytes``, the bytes of the trajectories
+        brought to the host (a recorded core included)."""
         self.worker_id = worker_id
         self.engine = engine
         self.sink = sink
@@ -45,6 +50,8 @@ class RolloutWorker:
         self.param_version = 0            # version driving the current scan
         self.param_refreshes = 0          # scans that picked up fresh params
         self.param_lag_total = 0          # sum of version deltas across scans
+        self.scan_time_s = 0.0            # dispatch -> trajectory on host
+        self.fetch_bytes = 0              # trajectory bytes on the host
         self.flush_time_s = 0.0           # trajectory on host -> sink done
         self.error: Optional[str] = None
         self._health = health             # optional HeartbeatRegistry
@@ -108,8 +115,11 @@ class RolloutWorker:
                 self.param_lag_total += version - self.param_version
                 self.param_refreshes += 1
                 self.param_version = version
+            t0 = time.perf_counter()
             with maybe_span(tr, "rollout/scan"):
-                traj = self.engine.rollout(params)      # (T, E, ...)
+                traj = self.engine.rollout(params, tracer=tr)  # (T, E, ...)
+            self.scan_time_s += time.perf_counter() - t0
+            self.fetch_bytes += sum(x.nbytes for x in jax.tree.leaves(traj))
             t0 = time.perf_counter()
             with maybe_span(tr, "rollout/flush"):
                 rewards, dones = traj["rewards"], traj["dones"].astype(bool)

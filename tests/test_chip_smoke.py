@@ -52,6 +52,19 @@ def test_vtrace_device_phase_tiny():
     assert stats["learner_steps"] >= 3
 
 
+def test_impala_deep_device_phase_tiny():
+    from repro.configs.impala_atari import ImpalaConfig
+
+    cfg = ImpalaConfig(obs_size=20, obs_channels=2, channels=(4, 8, 8),
+                       fc_dim=16, core_dim=16)
+    stats = chip_smoke.phase_impala_deep_device(
+        cfg, num_workers=1, lanes=4, unroll=6, learner_batch=4,
+        step_cost=64, seconds=2.0)
+    _no_errors(stats)
+    _ledger_exact(stats)
+    assert stats["learner_steps"] >= 3
+
+
 def test_vtrace_shm_hosts_phase_tiny():
     stats = chip_smoke.phase_vtrace_shm_hosts(
         actors_per_host=1, envs_per_actor=2, unroll=8, learner_batch=2,

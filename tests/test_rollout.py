@@ -15,11 +15,13 @@ from repro.envs.alesim import ALESimEnv
 from repro.envs.cartpole import CartPoleEnv
 from repro.envs.catch import CatchEnv
 from repro.rollout import DeviceRolloutEngine, RolloutWorker, action_key
+from repro.rollout.engine import first_inputs, next_inputs
 
 
 def _random_policy_apply(num_actions):
-    def policy_apply(params, core, obs, key):
-        return jax.random.randint(key, (obs.shape[0],), 0, num_actions), core
+    def policy_apply(params, core, inputs, key):
+        n = inputs.obs.shape[0]
+        return jax.random.randint(key, (n,), 0, num_actions), core
     return policy_apply
 
 
@@ -32,14 +34,16 @@ def _host_reference(env, E, T, seed, policy_apply, params=None):
     vstep = jax.vmap(env.step)
     state, obs = vreset(keys)
     key, core = action_key(seed), None
+    inputs = first_inputs(obs)
     out = {"obs": [], "actions": [], "rewards": [], "dones": []}
     for _ in range(T):
         key, sub = jax.random.split(key)
-        actions, core = policy_apply(params, core, obs, sub)
+        actions, core = policy_apply(params, core, inputs, sub)
         actions = actions.astype(jnp.int32)
-        out["obs"].append(np.asarray(obs))
+        out["obs"].append(np.asarray(inputs.obs))
         out["actions"].append(np.asarray(actions))
         state, obs, rewards, dones = vstep(state, actions)
+        inputs = next_inputs(obs, actions, rewards, dones)
         out["rewards"].append(np.asarray(rewards, np.float32))
         out["dones"].append(np.asarray(dones))
     return {k: np.stack(v) for k, v in out.items()}
@@ -86,9 +90,9 @@ def test_engine_with_recurrent_core_state():
     env = CatchEnv()
     E, T = 2, 7
 
-    def policy_apply(params, core, obs, key):
+    def policy_apply(params, core, inputs, key):
         core = core + 1
-        return jnp.zeros((obs.shape[0],), jnp.int32), core
+        return jnp.zeros((inputs.obs.shape[0],), jnp.int32), core
 
     eng = DeviceRolloutEngine(env, policy_apply, E, T,
                               init_core=lambda e: jnp.zeros((e,), jnp.int32))
@@ -194,7 +198,7 @@ def test_seed_system_device_with_learner_and_param_lag():
 
 
 def test_worker_error_is_surfaced():
-    def bad_policy(params, core, obs, key):
+    def bad_policy(params, core, inputs, key):
         raise TypeError("tracer-leak")
 
     eng = DeviceRolloutEngine(CatchEnv, bad_policy, 2, 4)

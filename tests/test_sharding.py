@@ -78,8 +78,8 @@ def test_num_gateways_validation_is_a_clear_valueerror():
 def test_engine_shards_validation_is_a_clear_valueerror():
     from repro.rollout import ShardedRolloutEngine
 
-    def pol(params, core, obs, key):
-        return np.zeros(obs.shape[0]), core
+    def pol(params, core, inputs, key):
+        return np.zeros(inputs.obs.shape[0]), core
 
     with pytest.raises(ValueError, match="num_shards"):
         ShardedRolloutEngine(CatchEnv, pol, 2, 4, num_shards=3)
@@ -312,8 +312,8 @@ def test_sharded_engine_frame_accounting_and_schema():
 
     from repro.rollout import RolloutWorker, ShardedRolloutEngine
 
-    def pol(params, core, obs, key):
-        return jax.random.randint(key, (obs.shape[0],), 0,
+    def pol(params, core, inputs, key):
+        return jax.random.randint(key, (inputs.obs.shape[0],), 0,
                                   CatchEnv.num_actions), core
 
     E, T = 5, 6                      # uneven split: shards of 3 and 2 lanes
@@ -342,8 +342,8 @@ def test_sharded_engine_frame_accounting_and_schema():
 def test_seed_system_engine_sharded_device_backend():
     import jax
 
-    def pol(params, core, obs, key):
-        return jax.random.randint(key, (obs.shape[0],), 0,
+    def pol(params, core, inputs, key):
+        return jax.random.randint(key, (inputs.obs.shape[0],), 0,
                                   CatchEnv.num_actions), core
 
     E, T = 4, 8

@@ -724,8 +724,9 @@ def test_sampling_policy_spans():
 
     from repro.onpolicy import SamplingPolicy
 
-    def apply_fn(params, obs):
-        return obs @ params, jnp.zeros(obs.shape[:-1])
+    def apply_fn(params, core, inputs):
+        obs = inputs["obs"]
+        return obs @ params, jnp.zeros(obs.shape[:-1]), core
 
     pol = SamplingPolicy(apply_fn, np.ones((50, 3), np.float32))
     assert pol.tracer is None
@@ -746,8 +747,8 @@ def test_rollout_worker_counts_flush_time_and_traces_each_scan():
 
     from repro.rollout import DeviceRolloutEngine, RolloutWorker
 
-    def policy_apply(params, core, obs, key):
-        return jax.random.randint(key, obs.shape[:1], 0, 3), core
+    def policy_apply(params, core, inputs, key):
+        return jax.random.randint(key, inputs.obs.shape[:1], 0, 3), core
 
     tr = Tracer(enabled=True)
     eng = DeviceRolloutEngine(CatchEnv, policy_apply, 2, 4, seed=0)
@@ -761,8 +762,12 @@ def test_rollout_worker_counts_flush_time_and_traces_each_scan():
     w.join()
     assert w.error is None, w.error
     assert w.flush_time_s > 0
+    assert w.scan_time_s > 0
+    # obs (T, E, 50) f32, actions i32, rewards f32 and dones bool per scan
+    assert w.fetch_bytes == w.iterations * 4 * 2 * (50 * 4 + 4 + 4 + 1)
     spans = _spans(tr)
-    assert set(spans) == {"rollout/scan", "rollout/flush"}
+    assert set(spans) == {"rollout/scan", "rollout/dispatch",
+                          "rollout/fetch", "rollout/flush"}
     assert len(spans["rollout/flush"]) >= 2
 
 
@@ -798,6 +803,7 @@ def test_r2d2_system_traces_every_layer_it_runs():
     assert timings["replay_adds"] == replay.adds
     assert timings["learner_post_s"] == ln.post_time_s
     assert timings["rollout_flush_s"] == 0.0
+    assert timings["rollout_scan_s"] == timings["rollout_fetch_bytes"] == 0
     assert timings["replay_add_s"] == replay.add_time_s >= replay.add_wait_s
     gauges = sys_._ops_ledger_gauges()
     assert gauges["timings/replay_add_wait_s"] == replay.add_wait_s

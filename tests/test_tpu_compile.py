@@ -154,3 +154,70 @@ def test_r2d2_replay_gather_compiles_for_v5e(one_chip):
     # the output is one batch, laid out in the chip's tiles
     assert one_batch <= mem.output_size_in_bytes < 1.25 * one_batch, mem
     assert mem.temp_size_in_bytes < one_batch // 8, mem
+
+
+def _impala_learner():
+    from repro.configs.impala_atari import ImpalaConfig
+    from repro.models.impala import impala_actor_critic
+    from repro.onpolicy import VTraceLearner
+
+    cfg = ImpalaConfig()
+    init_fn, apply_fn, init_core = impala_actor_critic(cfg)
+    vl = VTraceLearner(apply_fn, adamw(6e-4, max_grad_norm=40.0),
+                       init_core=init_core)
+    params = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    return cfg, vl, params, init_core
+
+
+def test_impala_deep_vtrace_train_step_compiles_for_v5e(one_chip):
+    """IMPALA's deep ResNet-LSTM learner step at the benchmark cell's batch
+    (32 unrolls of 20 frames of 84x84x4), unrolled from recorded cores."""
+    cfg, vl, params, _ = _impala_learner()
+    state = jax.eval_shape(vl.init_state, params)
+    b, t = 32, 20
+    frame = (cfg.obs_size, cfg.obs_size, cfg.obs_channels)
+    batch = {
+        "obs": jax.ShapeDtypeStruct((b, t) + frame, jnp.uint8),
+        "actions": jax.ShapeDtypeStruct((b, t), jnp.int32),
+        "rewards": jax.ShapeDtypeStruct((b, t), jnp.float32),
+        "discounts": jax.ShapeDtypeStruct((b, t), jnp.float32),
+        "behavior_logprobs": jax.ShapeDtypeStruct((b, t), jnp.float32),
+        "param_version": jax.ShapeDtypeStruct((b,), jnp.int32),
+        "core": jax.ShapeDtypeStruct((b, 2, cfg.core_dim), jnp.float32),
+        "prev_action": jax.ShapeDtypeStruct((b, t), jnp.int32),
+        "prev_reward": jax.ShapeDtypeStruct((b, t), jnp.float32),
+        "first": jax.ShapeDtypeStruct((b, t), jnp.bool_),
+    }
+    compiled, _ = _compile(vl.train_step, _shapes(state, one_chip),
+                           _shapes(batch, one_chip))
+    mem = compiled.memory_analysis()
+    # activations of 640 frames with their backward fit beside the rest
+    assert mem.temp_size_in_bytes < 8 * 2 ** 30, mem
+
+
+def test_impala_deep_64_lane_scan_compiles_for_v5e(one_chip):
+    """One rollout worker's fused scan: 64 `ALESimJaxEnv` lanes x 20 steps
+    of the deep ResNet-LSTM policy, recording each lane's core."""
+    from repro.envs.alesim import ALESimJaxEnv
+    from repro.rollout import DeviceRolloutEngine
+    from repro.rollout.engine import first_inputs
+
+    cfg, vl, params, init_core = _impala_learner()
+    lanes, t = 64, 20
+    eng = DeviceRolloutEngine(ALESimJaxEnv, vl.device_policy_apply(), lanes,
+                              t, init_core=init_core, with_logprobs=True)
+
+    def carry():
+        keys = jax.random.split(jax.random.PRNGKey(0), lanes)
+        env_state, obs = jax.vmap(eng.env.reset)(keys)
+        return (env_state, init_core(lanes), first_inputs(obs),
+                jax.random.PRNGKey(1))
+
+    compiled, _ = _compile(eng._build(vl.device_policy_apply(), t),
+                           _shapes(params, one_chip),
+                           _shapes(jax.eval_shape(carry), one_chip))
+    out = jax.eval_shape(eng._build(vl.device_policy_apply(), t), params,
+                         jax.eval_shape(carry))[1]
+    assert out["obs"].shape == (t, lanes, 84, 84, 4)
+    assert out["start"]["core"].shape == (lanes, 2, cfg.core_dim)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30

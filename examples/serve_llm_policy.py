@@ -67,8 +67,8 @@ def main():
         for _ in range(args.tokens):
             if cid == 0:
                 time.sleep(0.004)        # a deliberate straggler
-            reply = server.submit(cid, np.array([[tok]], np.int32)[0])
-            tok = int(reply.get(timeout=10.0))
+            reply = server.submit_batch(cid, np.array([[tok]], np.int32))
+            tok = int(reply.get(timeout=10.0)[0])
             results[cid].append(tok)
 
     t0 = time.perf_counter()

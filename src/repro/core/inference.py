@@ -29,11 +29,11 @@ slot rows and may call `policy_step` concurrently. `num_replicas=1` is
 bit-for-bit the historical single-loop server.
 
 The queue API below (`submit_batch` -> reply `get`) is the transport seam.
-`repro.transport` implements it twice: `InProcTransport` (the in-process
-default, identical to handing actors this server directly) and
-`SocketTransport`/`InferenceGateway` (a wire-level TCP transport so actors
-can live on remote CPU hosts — the paper's disaggregated provisioning; one
-gateway per replica composes with the sharding here).
+In-process actors call it on this server directly; `repro.transport`'s
+`InferenceGateway`s carry it over TCP or shared memory, feeding
+`submit_request`, so actors can live on remote CPU hosts — the paper's
+disaggregated provisioning (one gateway per replica composes with the
+sharding here).
 Replies are either an action array or a poison `ReplyError`: when the
 server dies or stops, every pending request is drained with one so no
 actor ever blocks forever on a reply that cannot come (fail-fast).
@@ -65,7 +65,6 @@ class InferenceRequest:
     actor_id: int
     obs: np.ndarray              # (E, ...) lane-batched observations
     reply: "queue.Queue"
-    scalar: bool = False         # legacy single-obs submit: unwrap the reply
     trace_seq: int = 0           # telemetry stitch id (0 = untraced)
     t_enqueue: float = field(default_factory=time.perf_counter)
 
@@ -192,7 +191,7 @@ class _Replica:
                 for r in batch:
                     a = actions[lanes:lanes + r.lanes]
                     lanes += r.lanes
-                    r.reply.put(a[0] if r.scalar else a)
+                    r.reply.put(a)
                     waits.append(t0 - r.t_enqueue)
             # ONE lock acquisition per batch: counters + histograms move
             # together, so no snapshot can see a batch counted without its
@@ -271,8 +270,8 @@ class InferenceServer:
             raise ValueError(
                 f"num_replicas={num_replicas} exceeds the max_batch="
                 f"{max_batch} lane budget: each replica needs at least one "
-                f"lane of batch budget (lower num_replicas or raise "
-                f"inference_batch)")
+                f"lane of batch budget (lower num_replicas or raise the "
+                f"lane budget, actors x lanes)")
         self.policy_step = policy_step
         self.max_batch = max_batch           # TOTAL lane budget per round
         self.deadline_ms = deadline_ms
@@ -399,12 +398,6 @@ class InferenceServer:
             # (each request is popped at most once, so no double replies)
             self._drain_pending(self.error or "inference server stopped")
         return r.reply
-
-    def submit(self, actor_id: int, obs: np.ndarray) -> "queue.Queue":
-        """Single-observation submit; the reply holds one action."""
-        return self.submit_request(InferenceRequest(
-            actor_id, np.asarray(obs)[None], queue.Queue(maxsize=1),
-            scalar=True))
 
     def submit_batch(self, actor_id: int, obs: np.ndarray,
                      trace_seq: int = 0) -> "queue.Queue":

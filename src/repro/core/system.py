@@ -34,11 +34,12 @@ independently of the rollout backend:
     reports the conserved frame ledger (generated = trained + dropped).
 
 The host backend additionally picks a transport (`repro.transport`):
-  * `transport="inproc"` (default): actor threads in this process, queue
-    round-trips — identical to the pre-transport behavior;
+  * `transport="inproc"` (default): actor threads in this process call
+    the `InferenceServer`'s `submit_batch` directly, no wire;
   * `transport="socket"`: actors move to `num_actor_hosts` spawned OS
-    processes (stand-ins for remote CPU hosts) that dial a TCP
-    `InferenceGateway` in front of the same `InferenceServer`; trajectory
+    processes (stand-ins for remote CPU hosts), each actor dialing a TCP
+    `InferenceGateway` in front of the same `InferenceServer` through its
+    own `SyncSocketTransport` connection; trajectory
     unrolls return over the wire into the same replay sink. Requires a
     picklable `env_factory` (class or module-level factory, not a lambda);
   * `transport="shm"`: same disaggregated layout, but each connection
@@ -97,11 +98,10 @@ class SeedSystem:
     def __init__(self, *, env_factory: Callable, policy_step: Optional[Callable] = None,
                  num_actors: int, unroll: int, envs_per_actor: int = 1,
                  backend: str = "host", policy_apply: Optional[Callable] = None,
-                 init_params=None, init_core: Optional[Callable] = None,
+                 init_core: Optional[Callable] = None,
                  train_step: Optional[Callable] = None, state=None,
                  learner_batch: int = 8, replay_capacity: int = 512,
                  min_replay: int = 16, deadline_ms: float = 5.0,
-                 inference_batch: Optional[int] = None,
                  transport: str = "inproc", num_actor_hosts: int = 1,
                  gateway_host: str = "127.0.0.1", gateway_port: int = 0,
                  num_replicas: int = 1, num_gateways: int = 1,
@@ -115,8 +115,7 @@ class SeedSystem:
                  gamma: Optional[float] = None,
                  policy_publish: Optional[Callable] = None,
                  telemetry=None, ops_port: Optional[int] = None,
-                 supervise_hosts: bool = False,
-                 max_host_restarts: int = 3, host_stall_s: float = 5.0,
+                 supervise_hosts: bool = False, host_stall_s: float = 5.0,
                  wire_reconnect=None, autoscale=None):
         if backend not in ("host", "device"):
             raise ValueError(f"unknown backend {backend!r}; use 'host' or 'device'")
@@ -274,7 +273,7 @@ class SeedSystem:
         # the publish/version seam exists for EVERY backend now: device
         # workers pull params from it, host/socket actors read the version
         # for staleness stamping, the on-policy queue for admission
-        self._live = {"params": init_params, "version": 0}
+        self._live = {"params": None, "version": 0}
         self._live_lock = threading.Lock()
         self.onpolicy_queue = None
         if onpolicy:
@@ -294,7 +293,7 @@ class SeedSystem:
             # raises ValueError when num_replicas exceeds the lane budget
             self.server = InferenceServer(
                 policy_step,
-                max_batch=inference_batch or max(num_actors * envs_per_actor, 1),
+                max_batch=max(num_actors * envs_per_actor, 1),
                 deadline_ms=deadline_ms, num_replicas=num_replicas,
                 telemetry=telemetry)
             if wire:
@@ -337,7 +336,6 @@ class SeedSystem:
                             "pool_timeout", msg))
                         if self._flightrec is not None else None),
                     supervise=supervise_hosts,
-                    max_host_restarts=max_host_restarts,
                     host_stall_s=host_stall_s,
                     reconnect=wire_reconnect,
                     fault_callback=self._host_fault,
@@ -356,12 +354,11 @@ class SeedSystem:
                 raise ValueError("backend='device' requires policy_apply")
             from repro.rollout import (DeviceRolloutEngine,
                                        RolloutWorker, ShardedRolloutEngine)
-            if init_params is None and isinstance(state, dict):
+            if isinstance(state, dict):
                 # workers must start from the learner's params, not None —
                 # and from the same pytree structure the first publish will
                 # have, or the fused scan recompiles mid-measurement
-                init_params = state.get("params")
-                self._live["params"] = init_params
+                self._live["params"] = state.get("params")
 
             def make_engine(i):
                 if engine_shards == 1:

@@ -48,6 +48,9 @@ from typing import Any, List, Optional, Tuple
 
 from repro.fault.supervisor import RestartBudget
 
+# respawns a supervised pool allows per run window before it gives up
+MAX_HOST_RESTARTS = 3
+
 
 @dataclass
 class ActorHostConfig:
@@ -299,8 +302,7 @@ class ActorHostPool:
                  coalesce: bool = True, telemetry: bool = False,
                  pid_callback=None, heartbeat_callback=None,
                  heartbeat_close=None, failure_callback=None,
-                 supervise: bool = False, max_host_restarts: int = 3,
-                 host_stall_s: float = 5.0,
+                 supervise: bool = False, host_stall_s: float = 5.0,
                  min_respawn_window_s: float = 0.25,
                  reconnect=None, fault_callback=None,
                  elastic: bool = False):
@@ -336,7 +338,6 @@ class ActorHostPool:
         # --- supervision (all opt-in: supervise=False is the historical
         # fail-fast pool, byte-identical collect loop semantics) ---------
         self.supervise = supervise
-        self.max_host_restarts = max_host_restarts
         self.host_stall_s = host_stall_s
         self.min_respawn_window_s = min_respawn_window_s
         self.reconnect = reconnect   # BackoffPolicy for child transports
@@ -596,7 +597,7 @@ class ActorHostPool:
         self._next_host_id = self.num_hosts
         t0 = time.perf_counter()
         window_end = t0 + seconds
-        budget = RestartBudget(self.max_host_restarts,
+        budget = RestartBudget(MAX_HOST_RESTARTS,
                                window_s=max(seconds + self.grace_s, 60.0))
         for host_id, actor_ids in enumerate(self._partitions()):
             self._spawn(host_id, actor_ids, addresses, seconds, 0,

@@ -1,36 +1,37 @@
 """Wire-level inference transport — the gRPC-shaped seam, realized.
 
-`core.inference` promised that its queue API was "the only seam a
-networked transport would replace"; this package replaces it. Four
-layers:
+`core.inference`'s queue API (`submit_batch` -> reply `get`) is the seam
+that in-process actors call on the server itself; this package carries
+the same API over a wire. Three layers:
 
   * `repro.transport.codec` — length-prefixed binary frames (no pickle on
     the hot path): requests, replies, errors, trajectory unrolls, batched
     unrolls, and the HELLO/SHM negotiation frames. Encoders come in two
     shapes: `encode_*` (one joined `bytes`) and `encode_*_parts`
     (zero-copy buffer-view lists for `socket.sendmsg` scatter-gather);
-  * `repro.transport.local.InProcTransport` — the identity transport over
-    a local `InferenceServer` (the default; bit-for-bit today's behavior);
   * `repro.transport.shm.ShmRing` — a fixed-capacity single-producer /
     single-consumer ring over `multiprocessing.shared_memory`, carrying
     whole wire frames between co-located processes without a syscall;
-  * `repro.transport.socket` — `SocketTransport` / `SyncSocketTransport`
-    (actor-host clients) and `InferenceGateway` (learner-side acceptor)
-    over TCP, preserving the batching deadline and per-(actor, lane)
-    recurrent-slot semantics across the wire. `ShmTransport` extends the
-    sync client: after HELLO grants CODEC_SHM (loopback peers only) it
-    rides a ring pair and keeps TCP as the spill/control/liveness channel.
+  * `repro.transport.socket` — `SyncSocketTransport` (the actor-host
+    client, one connection per actor) and `InferenceGateway` (the
+    learner-side acceptor) over TCP, preserving the batching deadline and
+    per-(actor, lane) recurrent-slot semantics across the wire.
+    `ShmTransport` extends the client: after HELLO grants CODEC_SHM
+    (loopback peers only) it rides a ring pair and keeps TCP as the
+    spill/control/liveness channel.
 
 Transport decision matrix — which plane, which codec:
 
   placement               transport        why
   ----------------------  ---------------  --------------------------------
-  actors in-process       "inproc"         no wire at all; the baseline
-  co-located processes    "shm"            ring memcpy beats loopback TCP:
-                                           no per-frame syscalls or reader
-                                           wakeups; TCP remains for spill
-  separate hosts          "socket" (tcp)   the only option once frames
-                                           cross a NIC
+  actors in-process       "inproc"         no wire at all: actors call
+                                           the `InferenceServer` itself
+  co-located processes    "shm"            `ShmTransport`: ring memcpy
+                                           beats loopback TCP (no per-
+                                           frame syscalls or reader
+                                           wakeups); TCP remains for spill
+  separate hosts          "socket" (tcp)   `SyncSocketTransport`: the only
+                                           option once frames cross a NIC
 
   payload                 codec flag       discipline
   ----------------------  ---------------  --------------------------------
@@ -62,11 +63,9 @@ from repro.transport.codec import (CODEC_ONPOLICY, CODEC_QUANT, CODEC_RLE,
                                    encode_trajectory,
                                    encode_trajectory_parts, parts_len,
                                    read_frame, rle_decode_u8, rle_encode_u8)
-from repro.transport.local import InProcTransport, Transport
 from repro.transport.shm import ShmRing, ShmRingError
 from repro.transport.socket import (InferenceGateway, ShmTransport,
-                                    SocketTransport, SyncSocketTransport,
-                                    sendmsg_all)
+                                    SyncSocketTransport, sendmsg_all)
 
 __all__ = [
     "CODEC_ONPOLICY", "CODEC_QUANT", "CODEC_RLE", "CODEC_SHM",
@@ -77,8 +76,7 @@ __all__ = [
     "encode_shm", "encode_traj_batch", "encode_traj_batch_parts",
     "encode_trajectory", "encode_trajectory_parts", "parts_len",
     "read_frame", "rle_decode_u8", "rle_encode_u8",
-    "InProcTransport", "Transport",
     "ShmRing", "ShmRingError",
-    "InferenceGateway", "ShmTransport", "SocketTransport",
-    "SyncSocketTransport", "sendmsg_all",
+    "InferenceGateway", "ShmTransport", "SyncSocketTransport",
+    "sendmsg_all",
 ]

@@ -115,12 +115,10 @@ KIND_HELLO = 5
 KIND_TRAJ_BATCH = 6
 KIND_SHM = 7
 
-FLAG_SCALAR = 0x01       # legacy single-obs submit: reply unwraps to obs[0]
 FLAG_RLE = 0x02          # >=1 ndarray payload in this frame is ENC_RLE
 FLAG_F16 = 0x04          # >=1 ndarray payload in this frame is ENC_F16
 FLAG_Q8 = 0x08           # >=1 ndarray payload in this frame is ENC_Q8
-_KNOWN_FLAGS = FLAG_SCALAR | FLAG_RLE | FLAG_F16 | FLAG_Q8
-_ARRAY_FLAGS = FLAG_RLE | FLAG_F16 | FLAG_Q8
+_KNOWN_FLAGS = FLAG_RLE | FLAG_F16 | FLAG_Q8   # each records an encoding
 
 # per-array encoding byte (the payload truth; frame flags are the record)
 ENC_RAW = 0
@@ -178,10 +176,6 @@ class Frame:
     traj_batch: Optional[List[Dict[str, np.ndarray]]] = None  # TRAJ_BATCH
     codecs: int = 0                          # HELLO capability bitmask
     shm: Optional[dict] = None               # SHM ring names + geometry
-
-    @property
-    def scalar(self) -> bool:
-        return bool(self.flags & FLAG_SCALAR)
 
 
 def parts_len(parts: Sequence) -> int:
@@ -337,7 +331,7 @@ def _frame(kind: int, actor_id: int, request_id: int, flags: int,
 
 
 def encode_request_parts(actor_id: int, request_id: int, obs: np.ndarray,
-                         scalar: bool = False, compress: bool = False,
+                         compress: bool = False,
                          quant: Optional[str] = None,
                          trace_seq: int = 0) -> List:
     """``compress``/``quant`` opt this frame into RLE / F16 / Q8 payloads —
@@ -345,19 +339,18 @@ def encode_request_parts(actor_id: int, request_id: int, obs: np.ndarray,
     ``CODEC_RLE`` / ``CODEC_QUANT`` (see `repro.transport.socket`).
     ``trace_seq`` (wire v3) stitches this request's spans across
     processes; 0 means untraced."""
-    flags = FLAG_SCALAR if scalar else 0
-    enc_flags, parts = _encode_ndarray_parts(obs, compress=compress,
-                                             quant=quant)
-    return _frame_parts(KIND_REQUEST, actor_id, request_id,
-                        flags | enc_flags, parts, trace_seq=trace_seq)
+    flags, parts = _encode_ndarray_parts(obs, compress=compress,
+                                         quant=quant)
+    return _frame_parts(KIND_REQUEST, actor_id, request_id, flags, parts,
+                        trace_seq=trace_seq)
 
 
 def encode_request(actor_id: int, request_id: int, obs: np.ndarray,
-                   scalar: bool = False, compress: bool = False,
-                   quant: Optional[str] = None, trace_seq: int = 0) -> bytes:
+                   compress: bool = False, quant: Optional[str] = None,
+                   trace_seq: int = 0) -> bytes:
     return b"".join(encode_request_parts(actor_id, request_id, obs,
-                                         scalar=scalar, compress=compress,
-                                         quant=quant, trace_seq=trace_seq))
+                                         compress=compress, quant=quant,
+                                         trace_seq=trace_seq))
 
 
 def encode_hello(codecs: int) -> bytes:
@@ -631,9 +624,9 @@ def decode_frame(body, max_frame: int = DEFAULT_MAX_FRAME,
         # cannot know how the bytes are encoded, so allocating from them
         # would be garbage at best and a decompression bomb at worst
         raise CodecError(f"unknown flag bits 0x{flags & ~_KNOWN_FLAGS:02x}")
-    if flags & _ARRAY_FLAGS and kind in (KIND_ERROR, KIND_HELLO, KIND_SHM):
+    if flags and kind in (KIND_ERROR, KIND_HELLO, KIND_SHM):
         raise CodecError(
-            f"array-encoding flags 0x{flags & _ARRAY_FLAGS:02x} are "
+            f"array-encoding flags 0x{flags:02x} are "
             f"invalid on frame kind {kind}")
     offset = _HEADER.size
     frame = Frame(kind=kind, actor_id=actor_id, request_id=request_id,

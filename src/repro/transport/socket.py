@@ -1,12 +1,15 @@
 """TCP + shared-memory transport: disaggregated actor hosts behind a wire.
 
-Client side — `SocketTransport`: all actor threads on one host share ONE
-TCP connection; a per-connection ``request_id`` demultiplexes replies back
-to the right actor's reply queue (gRPC-stream-shaped, like SEED RL's
-inference RPC). Trajectory unrolls ride the same connection as ``TRAJ``
-frames, so an actor host needs exactly one socket to the learner box.
-`SyncSocketTransport` is the per-actor variant (SEED's streaming-RPC
-shape): the submitting thread reads its own reply — zero wakeups.
+Client side — `SyncSocketTransport`: one TCP connection per actor thread
+(SEED's per-actor streaming-RPC shape); the submitting thread reads its
+own reply off the socket — zero wakeups. Trajectory unrolls ride the same
+connection as ``TRAJ`` frames, so an actor needs exactly one socket to
+the learner box. Its surface is what `repro.core.actor.Actor` programs
+against, the same as the in-process `InferenceServer`'s:
+``submit_batch(actor_id, obs[E, ...])`` returns a reply whose ``get()``
+yields the ``(E,)`` action array or a `repro.core.inference.ReplyError`
+(fail-fast poison); ``error`` is set once the endpoint has died, so
+actors poll it instead of blocking on a reply that will never come.
 `ShmTransport` extends it for co-located hosts: after a ``CODEC_SHM``
 HELLO grant the client creates a pair of `repro.transport.shm.ShmRing`
 segments and frames ride shared memory — zero syscalls — with the TCP
@@ -72,13 +75,12 @@ docstring for the system-wide matrix):
                              via `drop_pending()`          `dropped_fault`
 
 Reconnect is strictly opt-in (`reconnect=None` keeps every path
-bit-identical to the fail-fast behavior above). The multiplexed
-`SocketTransport` does NOT reconnect — its N-actors-one-wire sharing
-makes transparent re-submit ambiguous; deployments that want survival
-use the per-actor sync transports, where the one-in-flight-request
-contract makes recovery exact. One caveat: a recovered request re-runs
-the policy forward for that observation, so recurrent slots see one
-duplicated step per failover (feedforward policies are unaffected).
+bit-identical to the fail-fast behavior above). The client's
+one-in-flight-request contract is what makes recovery exact: the only
+request a reconnect can ever re-send is the one in flight. One caveat: a
+recovered request re-runs the policy forward for that observation, so
+recurrent slots see one duplicated step per failover (feedforward
+policies are unaffected).
 """
 
 import contextlib
@@ -114,7 +116,6 @@ from repro.transport.codec import (CODEC_ONPOLICY, CODEC_QUANT, CODEC_RLE,
                                    encode_trajectory,
                                    encode_trajectory_parts, read_frame,
                                    recv_exact)
-from repro.transport.local import Transport
 from repro.transport.shm import (DEFAULT_NUM_SLOTS, DEFAULT_SLOT_SIZE,
                                  ShmRing, ShmRingError)
 
@@ -202,10 +203,10 @@ def _offer_mask(compress: bool, onpolicy: bool, quant: Optional[str] = None,
 
 def _apply_hello_grant(transport, frame) -> None:
     """Apply a gateway HELLO grant to a client transport — ONE definition
-    for every read path (async recv loop, sync wait_hello, sync reply
-    read), so a future capability bit cannot be granted on one path and
-    missed on another. `_post_hello` is the subclass hook that runs AFTER
-    the grant lands (the shm transport creates its rings there)."""
+    for both read paths (`wait_hello` and the reply read), so a future
+    capability bit cannot be granted on one path and missed on the other.
+    `_post_hello` is the subclass hook that runs AFTER the grant lands
+    (the shm transport creates its rings there)."""
     transport._rle = bool(frame.codecs & CODEC_RLE)
     transport._onpolicy = bool(frame.codecs & CODEC_ONPOLICY)
     transport._quant = bool(frame.codecs & CODEC_QUANT)
@@ -229,230 +230,6 @@ def _check_quant(quant: Optional[str]) -> Optional[str]:
     if quant not in (None, "f16", "q8"):
         raise ValueError(f"quant={quant!r}; expected None, 'f16' or 'q8'")
     return quant
-
-
-class _ScalarReply:
-    """Unwrap a lane-batched (1,) reply to a scalar action client-side, so
-    the legacy single-obs ``submit`` never needs a wire flag round-trip."""
-
-    def __init__(self, inner: "queue.Queue"):
-        self._inner = inner
-
-    def get(self, timeout=None):
-        out = self._inner.get(timeout=timeout)
-        return out if isinstance(out, ReplyError) else out[0]
-
-
-class SocketTransport(Transport):
-    """Client half of the wire. One connection, many actor threads."""
-
-    def __init__(self, sock: _socket.socket,
-                 max_frame: int = DEFAULT_MAX_FRAME,
-                 compress: bool = False, onpolicy: bool = False,
-                 quant: Optional[str] = None, telemetry=None):
-        sock.setsockopt(_socket.IPPROTO_TCP, _socket.TCP_NODELAY, 1)
-        self._sock = sock
-        self._dialed_address: Optional[Address] = None
-        self.max_frame = max_frame
-        self._tracer = (telemetry.tracer
-                        if telemetry is not None and telemetry.enabled
-                        else None)
-        self._send_lock = threading.Lock()
-        self._pending: Dict[int, "queue.Queue"] = {}
-        self._pending_lock = threading.Lock()
-        self._next_id = 1          # 0 is the broadcast id — never assigned
-        self._closed = threading.Event()
-        self.error: Optional[str] = None
-        # capabilities start OFF and only turn on when the gateway's HELLO
-        # grants them (requests sent in the negotiation window go raw — a
-        # correct, just unoptimized, encoding)
-        self._rle = False
-        self._onpolicy = False
-        self._quant = False
-        self._trajbatch = False
-        self._shm_granted = False
-        self._quant_mode = _check_quant(quant)
-        self._hello = threading.Event()
-        self.param_version = 0     # latest behavior version seen on replies
-        offer = _offer_mask(compress, onpolicy, quant=quant)
-        self._onpolicy_offered = bool(offer & CODEC_ONPOLICY)
-        if offer:
-            try:
-                sock.sendall(encode_hello(offer))
-            except OSError as e:
-                self.error = f"send failed: {e}"
-        else:
-            self._hello.set()      # nothing to negotiate
-        self._recv_thread = threading.Thread(target=self._recv_loop,
-                                             daemon=True)
-        self._recv_thread.start()
-
-    @classmethod
-    def connect(cls, address: Address, timeout_s: float = 10.0,
-                max_frame: int = DEFAULT_MAX_FRAME,
-                compress: bool = False, onpolicy: bool = False,
-                **kwargs) -> "SocketTransport":
-        """Dial the gateway, retrying while it binds (actor hosts and the
-        learner box start concurrently). Extra kwargs reach the
-        constructor, so subclasses (sync / shm) share this dialer."""
-        deadline = time.perf_counter() + timeout_s
-        while True:
-            try:
-                sock = _socket.create_connection(address, timeout=2.0)
-                sock.settimeout(None)
-                t = cls(sock, max_frame=max_frame, compress=compress,
-                        onpolicy=onpolicy, **kwargs)
-                # remember where we dialed so the reconnect path can re-dial
-                # (a raw-socket constructor has no address to remember)
-                t._dialed_address = address
-                return t
-            except OSError:
-                if time.perf_counter() >= deadline:
-                    raise
-                time.sleep(0.05)
-
-    @property
-    def onpolicy_granted(self) -> bool:
-        """True once the gateway's HELLO granted CODEC_ONPOLICY."""
-        return self._onpolicy
-
-    @property
-    def _quant_eff(self) -> Optional[str]:
-        """Quantization mode actually on the wire: the requested mode once
-        (and only once) the gateway granted CODEC_QUANT."""
-        return self._quant_mode if self._quant else None
-
-    def _post_hello(self):
-        """Subclass hook: runs after every HELLO grant is applied."""
-
-    def wait_hello(self, timeout_s: float = 5.0) -> bool:
-        """Block until the gateway answered our HELLO (or no offer was
-        made). Returns False on timeout/error — callers that REQUIRE a
-        capability should fail fast rather than stream stripped frames."""
-        return self._hello.wait(timeout=timeout_s) and self.error is None
-
-    # ------------------------------------------------------- actor surface
-
-    def submit_batch(self, actor_id: int, obs: np.ndarray,
-                     trace_seq: int = 0) -> "queue.Queue":
-        obs = np.asarray(obs)
-        reply: "queue.Queue" = queue.Queue(maxsize=1)
-        if self.error is not None or self._closed.is_set():
-            reply.put(ReplyError(self.error or "transport closed"))
-            return reply
-        with self._pending_lock:
-            request_id = self._next_id
-            self._next_id += 1
-            self._pending[request_id] = reply
-        try:
-            self._send_parts(encode_request_parts(
-                actor_id, request_id, obs, compress=self._rle,
-                quant=self._quant_eff, trace_seq=trace_seq))
-        except OSError as e:
-            self._fail(f"send failed: {e}")
-        return reply
-
-    def submit(self, actor_id: int, obs: np.ndarray):
-        return _ScalarReply(
-            self.submit_batch(actor_id, np.asarray(obs)[None]))
-
-    def send_trajectory(self, arrays: Dict[str, np.ndarray],
-                        actor_id: int = 0):
-        """Trajectory sink over the same wire (``flush_lane_unrolls``
-        schema); drops silently once the transport has failed — the actor
-        is already being torn down on `error`. (This multiplexed client
-        sends one TRAJ frame per record; the per-actor sync client is the
-        one that coalesces, since its flush boundary is unambiguous.)"""
-        if self.error is not None or self._closed.is_set():
-            return
-        if self._onpolicy_offered and not self._hello.is_set():
-            # an offered grant races the first unroll only at connect
-            # time (the gateway answers HELLO immediately): wait it out
-            # rather than strip metadata the deployment asked for
-            self._hello.wait(timeout=5.0)
-        if not self._onpolicy:
-            arrays = _strip_onpolicy_keys(arrays)
-        tr = self._tracer
-        seq = next_trace_seq() if tr is not None else 0
-        try:
-            with (tr.trace_span("wire/traj_send", seq=seq)
-                  if tr is not None else _NOOP_CTX):
-                self._send_parts(encode_trajectory_parts(
-                    actor_id, arrays, compress=self._rle,
-                    quant=self._quant_eff, trace_seq=seq))
-        except OSError as e:
-            self._fail(f"send failed: {e}")
-
-    def close(self):
-        self._closed.set()
-        try:
-            self._sock.shutdown(_socket.SHUT_RDWR)
-        except OSError:
-            pass
-        self._sock.close()
-        self._recv_thread.join(timeout=5.0)
-
-    # ------------------------------------------------------------ plumbing
-
-    def _send(self, frame: bytes):
-        with self._send_lock:
-            self._sock.sendall(frame)
-
-    def _send_parts(self, parts: List):
-        with self._send_lock:
-            sendmsg_all(self._sock, parts)
-
-    def _fail(self, message: str):
-        """Poison every pending reply so no actor blocks on a dead wire."""
-        if self.error is None:
-            self.error = message
-        with self._pending_lock:
-            pending, self._pending = self._pending, {}
-        for reply in pending.values():
-            reply.put(ReplyError(self.error))
-
-    def _pop(self, request_id: int) -> Optional["queue.Queue"]:
-        with self._pending_lock:
-            return self._pending.pop(request_id, None)
-
-    def _recv_loop(self):
-        try:
-            while not self._closed.is_set():
-                frame = read_frame(lambda n: recv_exact(self._sock, n),
-                                   self.max_frame)
-                if frame is None:                      # clean peer close
-                    break
-                if frame.kind == KIND_REPLY:
-                    if frame.param_version > self.param_version:
-                        self.param_version = frame.param_version
-                    reply = self._pop(frame.request_id)
-                    if reply is not None:
-                        reply.put(frame.array)
-                elif frame.kind == KIND_HELLO:
-                    # the gateway granted (or refused) our codec offer
-                    _apply_hello_grant(self, frame)
-                    self._hello.set()
-                elif frame.kind == KIND_ERROR:
-                    if frame.request_id == 0:          # broadcast: all fail
-                        self._fail(frame.message)
-                    else:
-                        reply = self._pop(frame.request_id)
-                        if reply is not None:
-                            reply.put(ReplyError(frame.message))
-                else:
-                    raise CodecError(
-                        f"unexpected frame kind {frame.kind} on client")
-        except (OSError, CodecError) as e:
-            if not self._closed.is_set():
-                self._fail(f"connection lost: {e}")
-            return
-        except Exception as e:       # never die silently holding replies
-            self._fail(f"receiver crashed: {e!r}")
-            return
-        # clean EOF before OUR close() is a gateway shutdown: poison any
-        # in-flight requests and mark the wire dead so actors stop
-        if not self._closed.is_set():
-            self._fail("gateway closed the connection")
 
 
 class _ConnWriter:
@@ -620,14 +397,12 @@ class _SyncReply:
         return self._transport._read_reply(self._request_id, timeout)
 
 
-class SyncSocketTransport(Transport):
+class SyncSocketTransport:
     """One connection per actor thread, replies read synchronously.
 
-    The multiplexed `SocketTransport` pays two client-side thread wakeups
-    per reply (recv thread -> pending queue -> actor); under a busy GIL
-    each wakeup can convoy for milliseconds. This variant is SEED's
-    per-actor streaming-RPC shape instead: the actor thread that submitted
-    the request parses the reply off the socket itself — zero wakeups.
+    The actor thread that submitted the request parses the reply off the
+    socket itself, so no client-side thread wakeup sits on the round trip
+    (under a busy GIL each wakeup can convoy for milliseconds).
     NOT thread-safe: one actor, one in-flight request at a time (the
     actor loop's contract anyway). Trajectory sends from the same thread
     interleave safely because TRAJ frames are strictly client -> gateway.
@@ -689,7 +464,26 @@ class SyncSocketTransport(Transport):
             except OSError as e:
                 self.error = f"send failed: {e}"
 
-    connect = classmethod(SocketTransport.connect.__func__)
+    @classmethod
+    def connect(cls, address: Address, timeout_s: float = 10.0,
+                **kwargs) -> "SyncSocketTransport":
+        """Dial the gateway, retrying while it binds (actor hosts and the
+        learner box start concurrently). Extra kwargs reach the
+        constructor, so `ShmTransport` shares this dialer."""
+        deadline = time.perf_counter() + timeout_s
+        while True:
+            try:
+                sock = _socket.create_connection(address, timeout=2.0)
+                sock.settimeout(None)
+                t = cls(sock, **kwargs)
+                # remember where we dialed so the reconnect path can re-dial
+                # (a raw-socket constructor has no address to remember)
+                t._dialed_address = address
+                return t
+            except OSError:
+                if time.perf_counter() >= deadline:
+                    raise
+                time.sleep(0.05)
 
     @property
     def onpolicy_granted(self) -> bool:
@@ -749,10 +543,6 @@ class SyncSocketTransport(Transport):
                 # request id keeps any half-sent frame unambiguous
                 return self._send_request(actor_id, obs, trace_seq)
         return request_id
-
-    def submit(self, actor_id: int, obs: np.ndarray):
-        return _ScalarReply(
-            self.submit_batch(actor_id, np.asarray(obs)[None]))
 
     def send_trajectory(self, arrays: Dict[str, np.ndarray],
                         actor_id: int = 0):

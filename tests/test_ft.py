@@ -58,8 +58,8 @@ def test_inference_deadline_closes_partial_batches():
 
     srv = InferenceServer(policy_step, max_batch=64, deadline_ms=5.0)
     srv.start()
-    reply = srv.submit(0, np.zeros((4,), np.float32))
-    action = reply.get(timeout=2.0)
+    reply = srv.submit_batch(0, np.zeros((1, 4), np.float32))
+    action = reply.get(timeout=2.0)[0]
     srv.stop()
     assert action == 0
     assert seen and seen[0] == 1          # batch closed at deadline, not at 64

@@ -180,7 +180,6 @@ def test_seed_system_device_with_learner_and_param_lag():
 
     sys_ = SeedSystem(env_factory=CatchEnv, backend="device",
                       policy_apply=_random_policy_apply(3),
-                      init_params={"w": jnp.zeros(())},
                       num_actors=1, unroll=T, envs_per_actor=E,
                       train_step=train_step, state={"params": {"w": np.zeros(())},
                                                     "step": 0},

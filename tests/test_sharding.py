@@ -44,7 +44,7 @@ def test_num_replicas_validation_is_a_clear_valueerror():
     with pytest.raises(ValueError, match="num_replicas"):
         SeedSystem(env_factory=CatchEnv, policy_step=det_policy,
                    num_actors=1, unroll=4, envs_per_actor=2,
-                   inference_batch=2, num_replicas=4)
+                   num_replicas=4)
     # the device backend has no central server to shard
     with pytest.raises(ValueError, match="num_replicas"):
         SeedSystem(env_factory=CatchEnv, backend="device",

@@ -21,9 +21,7 @@ from repro.core.inference import InferenceServer, ReplyError
 from repro.envs.catch import CatchEnv
 from repro.launch.actor_host import ActorHostPool
 from repro.transport import codec
-from repro.transport.local import InProcTransport
-from repro.transport.socket import (InferenceGateway, SocketTransport,
-                                    SyncSocketTransport)
+from repro.transport.socket import InferenceGateway, SyncSocketTransport
 
 
 def det_policy(obs, ids):
@@ -79,10 +77,13 @@ def test_codec_reply_error_traj_roundtrip():
         assert np.array_equal(out.arrays[k], traj[k])
 
 
-def test_codec_scalar_flag_survives():
-    wire = codec.encode_request(1, 2, np.zeros((1, 4), np.float32),
-                                scalar=True)
-    assert codec.decode_frame(wire[4:]).scalar
+def test_codec_refuses_the_retired_single_observation_flag():
+    # bit 0x01 once marked a single-observation request; no encoder sets
+    # it now, so a frame carrying it is refused like any unknown bit
+    wire = bytearray(codec.encode_request(1, 2, np.zeros((1, 4), np.float32)))
+    wire[4 + 4] |= 0x01           # length prefix, magic, ver, kind: flags
+    with pytest.raises(codec.CodecError, match="unknown flag bits 0x01"):
+        codec.decode_frame(bytes(wire[4:]))
 
 
 def test_codec_rejects_truncated_frames():
@@ -251,20 +252,21 @@ def test_codec_property_roundtrip():
     roundtrip()
 
 
-# -------------------------------------------------- in-proc transport + fail-fast
+# ------------------------------------------- in-process seam + fail-fast
 
 def test_inproc_transport_is_the_server_behavior():
+    # in-process actors hold the server itself: its submit_batch is the
+    # whole in-process transport, fail-fast once stopped
     srv = InferenceServer(det_policy, max_batch=4, deadline_ms=2.0)
-    tr = InProcTransport(srv)
     srv.start()
     obs = np.random.rand(4, 50).astype(np.float32)
     try:
-        got = tr.submit_batch(0, obs).get(timeout=5.0)
+        got = srv.submit_batch(0, obs).get(timeout=5.0)
         assert np.array_equal(got, det_policy(obs, None))
-        assert tr.error is None
+        assert srv.error is None
     finally:
         srv.stop()
-    assert isinstance(tr.submit_batch(0, obs).get(timeout=1.0), ReplyError)
+    assert isinstance(srv.submit_batch(0, obs).get(timeout=1.0), ReplyError)
 
 
 def test_server_stop_drains_pending_with_poison():
@@ -343,15 +345,16 @@ def test_socket_loopback_roundtrip_and_recurrent_slots():
     gw = InferenceGateway(srv)
     srv.start()
     addr = gw.start()
-    tr = SocketTransport.connect(addr)
+    tr = SyncSocketTransport.connect(addr)
     try:
         obs = np.random.rand(4, 50).astype(np.float32)
         for _ in range(3):
             got = tr.submit_batch(11, obs).get(timeout=5.0)
             assert np.array_equal(got, det_policy(obs, None))
-        # scalar (legacy) submit unwraps client-side
-        scalar = tr.submit(12, np.zeros(50, np.float32)).get(timeout=5.0)
-        assert np.ndim(scalar) == 0
+        # a one-lane request is one slot and one action
+        one = tr.submit_batch(12, np.zeros((1, 50), np.float32)).get(
+            timeout=5.0)
+        assert one.shape == (1,)
         # 4 lanes of actor 11 + 1 lane of actor 12 = 5 distinct slots, and
         # lane slots are stable across repeated requests
         assert srv.num_slots == 5
@@ -398,7 +401,7 @@ def test_transport_poisons_pending_on_gateway_loss():
     gw = InferenceGateway(srv)
     srv.start()
     addr = gw.start()
-    tr = SocketTransport.connect(addr)
+    tr = SyncSocketTransport.connect(addr)
     try:
         reply = tr.submit_batch(0, np.zeros((1, 4), np.float32))
         time.sleep(0.1)

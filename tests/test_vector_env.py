@@ -199,13 +199,15 @@ def test_inference_server_deadline_cuts_partial_batch():
 
 
 def test_inference_server_scalar_submit_back_compat():
+    # one observation is a one-lane request: its reply holds one action
     def policy_step(obs, ids):
         return np.full((obs.shape[0],), 7, np.int32)
 
     srv = InferenceServer(policy_step, max_batch=1, deadline_ms=5.0)
     srv.start()
     try:
-        a = srv.submit(3, np.zeros((4,), np.float32)).get(timeout=5.0)
+        a = srv.submit_batch(3, np.zeros((1, 4), np.float32)).get(
+            timeout=5.0)[0]
     finally:
         srv.stop()
     assert int(a) == 7 and np.ndim(a) == 0

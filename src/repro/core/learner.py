@@ -6,7 +6,8 @@ V-trace), runs the jitted/pjitted train_step, and publishes fresh params
 to the inference server under a version counter. Periodic checkpointing
 and restart-on-failure live here (see repro.checkpoint). Always-on
 counters time each step's parts; with telemetry each part is also a
-``learner/*`` span."""
+``learner/*`` span. A step's scalar metrics stay on the device: they
+cross to the host once, when something reads `Learner.metrics`."""
 
 import queue
 import threading
@@ -59,7 +60,12 @@ class Learner:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.steps = 0
-        self.metrics: Dict[str, float] = {}
+        # the last step's scalar metrics, left on the device; `metrics`
+        # pulls them once per step when read (`metrics_pulls` counts that)
+        self._step_metrics: Dict[str, Any] = {}
+        self._pulled: tuple = (self._step_metrics, {})
+        self._pull_lock = threading.Lock()
+        self.metrics_pulls = 0
         # always-on counters: seconds waiting for a batch, training on it
         # (its transfer to the device, inside the train step's call,
         # included) and after the step (see _post_step)
@@ -79,6 +85,21 @@ class Learner:
             self._h_train = None
             self._h_wait = None
         self._health = getattr(telemetry, "health", None)
+
+    @property
+    def metrics(self) -> Dict[str, float]:
+        """The last step's scalar metrics as floats: one transfer of the
+        whole dict at the first read after a step, cached until the next."""
+        with self._pull_lock:           # readers only; the loop never waits
+            dev = self._step_metrics
+            held, values = self._pulled
+            if held is not dev:
+                with maybe_span(self._tracer, "learner/metrics_pull"):
+                    values = {k: float(v)
+                              for k, v in jax.device_get(dev).items()}
+                self.metrics_pulls += 1
+                self._pulled = (dev, values)
+            return values
 
     @property
     def stopped(self) -> bool:
@@ -128,12 +149,11 @@ class Learner:
             self.post_time_s += time.perf_counter() - t2
 
     def _post_step(self, tr, metrics, info):
-        """What follows a train step: the scalar metrics pulled to the
-        host, the replay priorities, the publish and the checkpoint."""
-        with maybe_span(tr, "learner/metrics_pull"):
-            self.metrics = {k: float(np.asarray(v).mean())
-                            for k, v in metrics.items()
-                            if np.asarray(v).ndim == 0}
+        """What follows a train step: the scalar metrics kept (on the
+        device, by reference), the replay priorities, the publish and the
+        checkpoint."""
+        self._step_metrics = {k: v for k, v in metrics.items()
+                              if np.ndim(v) == 0}
         if self.priority_update and "priorities" in metrics:
             with maybe_span(tr, "learner/priority_update"):
                 self.priority_update(info, np.asarray(metrics["priorities"]))

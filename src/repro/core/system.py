@@ -505,6 +505,7 @@ class SeedSystem:
             "learner_wait_s": ln.wait_time_s if ln else 0.0,
             "learner_train_s": ln.train_time_s if ln else 0.0,
             "learner_post_s": ln.post_time_s if ln else 0.0,
+            "learner_metrics_pulls": ln.metrics_pulls if ln else 0,
             "replay_samples": rp.samples,
             "replay_sample_s": rp.sample_time_s,
             "replay_adds": rp.adds,

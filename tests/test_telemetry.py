@@ -557,7 +557,10 @@ def test_learner_fills_the_post_counter():
     counted = ln.wait_time_s + ln.train_time_s + ln.post_time_s
     assert 0 <= wall - counted < 0.01
     assert ln.wait_time_s > 0 and ln.train_time_s > 0
+    # the step's scalars stay on the device until something reads them
+    assert ln.metrics_pulls == 0
     assert ln.metrics["loss"] == pytest.approx(12.0 + 6.0)
+    assert ln.metrics_pulls == 1
     # the step gets the batch as the batch source made it: its transfer
     # is the jitted call's, inside the train interval
     assert all(isinstance(v, np.ndarray) for v in seen[0].values())
@@ -574,8 +577,7 @@ def test_learner_spans_nest_under_the_step():
     ln.run_steps(2)
     spans = _spans(tel.tracer)
     children = ("learner/batch", "learner/train", "learner/post")
-    post_children = ("learner/metrics_pull", "learner/priority_update",
-                     "learner/publish")
+    post_children = ("learner/priority_update", "learner/publish")
     assert set(spans) == {"learner/step", *children, *post_children}
     assert len(spans["learner/step"]) == 2
     for i, step in enumerate(spans["learner/step"]):
@@ -583,6 +585,57 @@ def test_learner_spans_nest_under_the_step():
             assert _inside(spans[name][i], step), name
         for name in post_children:
             assert _inside(spans[name][i], spans["learner/post"][i]), name
+
+
+def test_learner_metrics_pull_once_per_step_at_the_reader():
+    from repro.core.learner import Learner
+
+    tel = Telemetry(process_name="learner")
+    ln = Learner(_fake_train_step([]),
+                 {"step": np.zeros((), np.int32), "params": np.zeros(())},
+                 _Batches(), telemetry=tel)
+    assert ln.metrics == {} and ln.metrics_pulls == 0
+    ln.run_steps(1)
+    first = ln.metrics
+    assert ln.metrics == first == {"loss": pytest.approx(18.0)}
+    assert ln.metrics_pulls == 1
+    assert len(_spans(tel.tracer)["learner/metrics_pull"]) == 1
+    ln.run_steps(1)
+    assert ln.metrics_pulls == 1              # a step alone pulls nothing
+    assert ln.metrics["loss"] == pytest.approx(18.0)
+    assert ln.metrics_pulls == 2
+    assert len(_spans(tel.tracer)["learner/metrics_pull"]) == 2
+
+
+def test_learner_steps_make_no_device_to_host_transfer(monkeypatch):
+    import jax
+
+    from repro.core.learner import Learner
+
+    ln = Learner(_fake_train_step([]),
+                 {"step": np.zeros((), np.int32), "params": np.zeros(())},
+                 _Batches(), publish=lambda p, s: None)
+    ln.run_steps(1)                             # compile outside the count
+    # the transfer guard does not fire on the CPU backend, so count the
+    # host calls that would move a device array
+    pulls = []
+
+    def counting(fn):
+        def wrapped(x, *a, **k):
+            if any(isinstance(leaf, jax.Array)
+                   for leaf in jax.tree_util.tree_leaves(x)):
+                pulls.append(fn.__name__)
+            return fn(x, *a, **k)
+        return wrapped
+
+    for mod, name in ((np, "asarray"), (np, "array"), (jax, "device_get")):
+        monkeypatch.setattr(mod, name, counting(getattr(mod, name)))
+    with jax.transfer_guard_device_to_host("disallow"):
+        ln.run_steps(3)
+    assert ln.steps == 4 and pulls == []
+    assert ln.metrics_pulls == 0
+    assert ln.metrics["loss"] == pytest.approx(18.0)
+    assert pulls == ["device_get"]
 
 
 def test_replay_counts_samples_adds_and_the_adds_lock_wait():
@@ -802,6 +855,7 @@ def test_r2d2_system_traces_every_layer_it_runs():
     assert timings["replay_samples"] == replay.samples
     assert timings["replay_adds"] == replay.adds
     assert timings["learner_post_s"] == ln.post_time_s
+    assert timings["learner_metrics_pulls"] == ln.metrics_pulls
     assert timings["rollout_flush_s"] == 0.0
     assert timings["rollout_scan_s"] == timings["rollout_fetch_bytes"] == 0
     assert timings["replay_add_s"] == replay.add_time_s >= replay.add_wait_s
